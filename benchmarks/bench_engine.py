@@ -262,6 +262,8 @@ def run_drift(n_big: int = 3, n_small: int = 4, reps: int = 2, f: int = 32,
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--graphs", type=int, default=6)
     ap.add_argument("--reps", type=int, default=None,

@@ -271,6 +271,8 @@ def run(n_graphs: int = 6, n_requests: int = 96, rate_hz: float = 150.0,
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="deterministic scheduler simulation only "

@@ -84,7 +84,7 @@ def _ell_launches(part, meta, dispatch: str) -> int:
 
 def _ell_roofline(sc, f: int, tune: dict) -> dict:
     """Analytic DMA/compute bounds of the class's ragged launch."""
-    from repro.analysis.roofline import HBM_BW, PEAK_FLOPS
+    from repro.analysis.roofline import V5E, peaks_for
     from repro.kernels.ell_spmm import contract_cost, ragged_ell_contract
     knobs = {k: v for k, v in tune.items()
              if k in ("bf", "max_bands", "buffer_depth", "gu")}
@@ -92,8 +92,9 @@ def _ell_roofline(sc, f: int, tune: dict) -> dict:
                             sc.n_col_tiles, sc.tile, f,
                             segments=sc.bands, **knobs)
     cost = contract_cost(c)
-    return {"dma_s": cost["hbm_bytes"] / HBM_BW,
-            "compute_s": cost["flops"] / PEAK_FLOPS}
+    peaks = peaks_for(V5E)
+    return {"dma_s": cost["hbm_bytes"] / peaks.hbm_bw,
+            "compute_s": cost["flops"] / peaks.flops}
 
 
 def run(verbose: bool = True, dispatches=("ragged",), backend: str = "xla",
@@ -219,6 +220,8 @@ def run(verbose: bool = True, dispatches=("ragged",), backend: str = "xla",
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--dispatch", default="ragged",
                     choices=list(DISPATCHES) + ["all"],

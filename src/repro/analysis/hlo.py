@@ -16,23 +16,13 @@ import re
 def count_pallas_calls(jaxpr) -> int:
     """Number of ``pallas_call`` eqns in a jaxpr, including sub-jaxprs.
 
-    Accepts an open ``Jaxpr`` (``jax.make_jaxpr(fn)(x).jaxpr``); recurses
-    through every ClosedJaxpr/Jaxpr found in eqn params (pjit bodies,
-    control flow branches, ...).
+    Accepts an open ``Jaxpr`` (``jax.make_jaxpr(fn)(x).jaxpr``); walks
+    every nested jaxpr (jit bodies, control flow branches, ...) with the
+    static analyzer's walker.
     """
-    import jax
-
-    n = 0
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            n += 1
-        for v in eqn.params.values():
-            for x in (v if isinstance(v, (list, tuple)) else (v,)):
-                if isinstance(x, jax.core.ClosedJaxpr):
-                    n += count_pallas_calls(x.jaxpr)
-                elif isinstance(x, jax.core.Jaxpr):
-                    n += count_pallas_calls(x)
-    return n
+    from repro.analysis.static.jaxpr_pass import iter_eqns
+    return sum(1 for eqn in iter_eqns(jaxpr)
+               if eqn.primitive.name == "pallas_call")
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,

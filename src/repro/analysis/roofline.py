@@ -1,9 +1,8 @@
 """Roofline-term derivation from a compiled (dry-run) artifact.
 
-TPU v5e constants (per chip):
-  peak bf16 compute   197 TFLOP/s
-  HBM bandwidth       819 GB/s
-  ICI per link        ~50 GB/s   (bidirectional aggregate per link)
+Per-chip peaks live in one table, ``PEAKS``, keyed by the device kind
+JAX reports (``jax.Device.device_kind``); a kind the table lacks is an
+error (``peaks_for``), never a default.
 
 Terms (seconds, per training/serving step, per chip):
   compute    = HLO_FLOPs / (chips * peak)
@@ -22,10 +21,28 @@ import json
 
 from .hlo import collective_summary
 
-PEAK_FLOPS = 197e12          # bf16 per chip
-HBM_BW = 819e9               # bytes/s per chip
-ICI_BW = 50e9                # bytes/s effective per chip (single link class)
-DCN_BW = 6.25e9              # bytes/s per chip across pods (~50 Gb/s)
+
+@dataclasses.dataclass(frozen=True)
+class DevicePeaks:
+    flops: float     # peak bf16 FLOP/s per chip
+    hbm_bw: float    # HBM bytes/s per chip
+    ici_bw: float    # ICI bytes/s per link
+
+
+# Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM,
+# 1,600 Gbit/s of ICI over 4 links (50 GB/s each).
+V5E = "TPU v5 lite"
+PEAKS = {V5E: DevicePeaks(flops=197e12, hbm_bw=819e9, ici_bw=50e9)}
+
+
+def peaks_for(device_kind: str) -> DevicePeaks:
+    """The published peaks of ``device_kind``; raises for a kind the
+    table does not hold."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; known: {sorted(PEAKS)}") from None
 
 
 @dataclasses.dataclass
@@ -40,18 +57,23 @@ class Roofline:
     model_flops: float
     per_device_memory: float          # bytes (peak, from memory_analysis)
     collectives: dict
+    device_kind: str                  # the chip the terms are priced on
+
+    @property
+    def peaks(self) -> DevicePeaks:
+        return peaks_for(self.device_kind)
 
     @property
     def t_compute(self) -> float:
-        return self.hlo_flops / PEAK_FLOPS
+        return self.hlo_flops / self.peaks.flops
 
     @property
     def t_memory(self) -> float:
-        return self.hlo_bytes / HBM_BW
+        return self.hlo_bytes / self.peaks.hbm_bw
 
     @property
     def t_collective(self) -> float:
-        return self.collective_bytes / ICI_BW
+        return self.collective_bytes / self.peaks.ici_bw
 
     @property
     def bottleneck(self) -> str:
@@ -71,12 +93,13 @@ class Roofline:
     @property
     def mfu_bound(self) -> float:
         """Model-flops utilization at the roofline bound (the score)."""
-        t_model = self.model_flops / (self.chips * PEAK_FLOPS)
+        t_model = self.model_flops / (self.chips * self.peaks.flops)
         return t_model / max(self.t_bound, 1e-30)
 
     def to_dict(self) -> dict:
         return {
             "arch": self.arch, "cell": self.cell, "mesh": self.mesh,
+            "device_kind": self.device_kind,
             "chips": self.chips, "hlo_flops": self.hlo_flops,
             "hlo_bytes": self.hlo_bytes,
             "collective_bytes": self.collective_bytes,
@@ -91,30 +114,13 @@ class Roofline:
         }
 
 
-def merge_cost_analysis(ca) -> dict:
-    """Normalize ``Compiled.cost_analysis()`` output to a flat dict.
-
-    Older JAX returns a single dict; newer JAX returns a list with one
-    dict per executable module (usually length 1). Numeric entries are
-    summed across modules; non-numeric entries keep the first value seen.
-    """
-    if ca is None:
-        return {}
-    if isinstance(ca, dict):
-        return dict(ca)
-    merged: dict = {}
-    for entry in ca:
-        for k, v in (entry or {}).items():
-            try:
-                merged[k] = merged.get(k, 0.0) + float(v)
-            except (TypeError, ValueError):
-                merged.setdefault(k, v)
-    return merged
-
-
 def analyze_compiled(arch, cell, mesh_name, chips, compiled,
-                     model_flops) -> Roofline:
-    ca = merge_cost_analysis(compiled.cost_analysis())
+                     model_flops, *, device_kind: str) -> Roofline:
+    """Roofline terms of ``compiled`` priced on ``device_kind``'s peaks
+    (the chip the program targets, which a dry-run compiled on other
+    devices must name)."""
+    peaks_for(device_kind)
+    ca = compiled.cost_analysis()
     flops = float(ca.get("flops", 0.0))
     byts = float(ca.get("bytes accessed", 0.0))
     try:
@@ -129,7 +135,7 @@ def analyze_compiled(arch, cell, mesh_name, chips, compiled,
     summ = collective_summary(text)
     return Roofline(arch, cell, mesh_name, chips, flops, byts,
                     float(summ["total_traffic_bytes"]), model_flops, mem,
-                    summ)
+                    summ, device_kind)
 
 
 def save_json(records, path):

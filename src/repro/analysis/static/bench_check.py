@@ -21,8 +21,9 @@ anything. The schema is deliberately flat and tiny:
 file does not parse or violates this schema — a malformed file is worse
 than no file, because a future regression gate would silently skip it.
 Schema 2 added the ``provenance`` block; ``write_bench_json`` collects
-it automatically (best-effort fallbacks keep the writers dependency-
-free), and schema-1 files fail the check until reseeded.
+it automatically (a writer that cannot read the JAX backend fails
+rather than record a guess), and schema-1 files fail the check until
+reseeded.
 """
 from __future__ import annotations
 
@@ -69,11 +70,13 @@ def flatten_metrics(obj, prefix: str = "") -> dict:
 
 
 def collect_provenance() -> dict:
-    """Best-effort run provenance for a trajectory file.
+    """Run provenance for a trajectory file.
 
-    Every value is a non-empty string by construction — the schema
-    check requires that, and a writer must never fail because git or
-    jax is unavailable ("unknown"/"none" record that honestly).
+    Every value is a non-empty string — the schema check requires that.
+    A checkout without git records ``"unknown"`` for the sha; the JAX
+    version and backend are read from JAX itself, and a failure to read
+    them raises: a number filed under a guessed backend is worse than
+    no number.
     """
     try:
         sha = subprocess.run(
@@ -81,16 +84,10 @@ def collect_provenance() -> dict:
             timeout=10).stdout.strip()
     except (OSError, subprocess.SubprocessError):
         sha = ""
-    try:
-        import jax
-        jax_version = jax.__version__
-        backend = jax.default_backend()
-    except Exception:       # noqa: BLE001 — provenance must not fail a run
-        jax_version = "none"
-        backend = "cpu"
+    import jax
     return {"git_sha": sha or "unknown",
-            "jax_version": jax_version or "none",
-            "backend": backend or "cpu"}
+            "jax_version": jax.__version__,
+            "backend": jax.default_backend()}
 
 
 def write_bench_json(path, bench: str, command: str, created: str,

@@ -48,14 +48,15 @@ FORBIDDEN_PRIMS = frozenset({
     "debug_print", "infeed", "outfeed", "device_put",
 })
 
-RAGGED_KERNEL = "_ragged_ell_kernel"
-FIXED_KERNEL = "_ell_kernel"
+# Launch names (the ``name=`` each kernel wrapper passes to pallas_call).
+RAGGED_KERNEL = "ragged_ell_spmm"
+FIXED_KERNEL = "ell_spmm"
 
 
 # -------------------------------------------------------- jaxpr walking -----
 
 def iter_eqns(jaxpr):
-    """Every eqn in ``jaxpr`` and all nested sub-jaxprs (pjit bodies,
+    """Every eqn in ``jaxpr`` and all nested sub-jaxprs (jit bodies,
     cond branches, pallas kernel bodies, ...)."""
     for eqn in jaxpr.eqns:
         yield eqn
@@ -85,7 +86,7 @@ def pallas_eqns(closed) -> list:
 
 
 def kernel_name(eqn) -> str:
-    return eqn.params["name_and_src_info"].name
+    return eqn.params["name"]
 
 
 # ---------------------------------------------- dead-lane abstract interp ----
@@ -196,7 +197,7 @@ class DeadLaneInterp:
             if len(outs) == 1:
                 return outs[0]
             return outs[0] if len(set(map(repr, outs))) == 1 else None
-        if sub and prim in ("pjit", "closed_call", "custom_jvp_call",
+        if sub and prim in ("jit", "closed_call", "custom_jvp_call",
                             "custom_vjp_call", "remat", "checkpoint"):
             inner = sub[0]
             sub_env = dict(zip(inner.invars, vals))
@@ -255,9 +256,8 @@ def check_single_launch(closed, n_layers: int,
                         label: str = "gcn") -> List[Finding]:
     """Ragged mode: one ragged ELL launch per layer, zero fixed-K ones."""
     names = [kernel_name(e) for e in pallas_eqns(closed)]
-    ragged = sum(1 for n in names if RAGGED_KERNEL in n)
-    fixed = sum(1 for n in names
-                if FIXED_KERNEL in n and RAGGED_KERNEL not in n)
+    ragged = names.count(RAGGED_KERNEL)
+    fixed = names.count(FIXED_KERNEL)
     findings: List[Finding] = []
     if ragged != n_layers:
         findings.append(Finding(
@@ -391,7 +391,7 @@ def run_jaxpr_pass(engine=None, name: str = "lint-fixture") -> List[Finding]:
         label="gcn-executor")
     findings += check_sentinel_layout(h)
     ragged = [e for e in pallas_eqns(closed)
-              if RAGGED_KERNEL in kernel_name(e)]
+              if kernel_name(e) == RAGGED_KERNEL]
     if ragged:
         findings += check_dead_lanes(ragged[0])
     elif h.sclass.ell_units:

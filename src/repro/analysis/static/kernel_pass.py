@@ -37,13 +37,14 @@ import numpy as np
 from repro.analysis.static.report import Finding
 from repro.engine.shape_class import (ClassNeed, ShapeClass, ShapePolicy,
                                       class_fits)
-from repro.kernels.ell_spmm import DEFAULT_BF, ragged_ell_contract
+from repro.kernels.ell_spmm import (DEFAULT_BF, VMEM_BUDGET_BYTES as
+                                    ELL_VMEM_LIMIT, ragged_ell_contract)
 from repro.kernels.tile_matmul import matmul_contract
 
-# Per-core VMEM by backend. TPU cores carry ~16 MiB of VMEM (see the
-# Pallas guide); the budget is what a *launch contract* may assume —
-# Mosaic needs the whole multi-buffered working set resident.
-VMEM_BUDGET_BYTES = {"tpu": 16 * 2 ** 20}
+# VMEM a launch contract may assume, by backend: on TPU the scoped
+# limit the ELL kernels pass to Mosaic (``vmem_limit_bytes``) — Mosaic
+# needs the whole multi-buffered working set resident inside it.
+VMEM_BUDGET_BYTES = {"tpu": ELL_VMEM_LIMIT}
 # Default in/out block buffering when a contract carries no
 # ``buffer_depth`` (the pipeline double-buffers); scratch is not
 # multiplied.

@@ -9,6 +9,7 @@ paper's partitioner exploits, so the partition statistics are realistic.
 from __future__ import annotations
 
 import dataclasses
+import zlib
 
 import numpy as np
 import scipy.sparse as sp
@@ -103,7 +104,9 @@ def make_paper_dataset(name: str, *, scale: float = 1.0, seed: int = 0):
     st = PAPER_DATASETS[name]
     n = max(int(st.n_vertices * scale), 64)
     n_edges = max(int(st.density * n * n), 4 * n)
-    rng = np.random.default_rng(seed + hash(name) % (2 ** 31))
+    # crc32, not hash(): str hashes are salted per process, and the same
+    # (name, seed) must give the same features in every run
+    rng = np.random.default_rng(seed + zlib.crc32(name.encode()))
     a, labels = sbm_graph(n, n_edges, seed=seed, return_labels=True)
     atil = normalized_adjacency(a)
     x = (rng.random((n, st.n_features)) < 0.05).astype(np.float32)

@@ -67,6 +67,9 @@ class GraphHandle:
     # exact pre-snapping shape requirements, kept so the lifecycle can
     # re-classify this graph on retirement without re-partitioning
     need: Optional[ClassNeed] = None
+    # device -> (part, weights) copies for replica lanes bound to other
+    # devices, made on a lane's first dispatch (`Engine._placed`)
+    copies: dict = dataclasses.field(default_factory=dict)
 
     @property
     def n_rows(self) -> int:
@@ -76,18 +79,22 @@ class GraphHandle:
 class _EngineReplicaView:
     """One replica's engine-facing view for a `repro.serving.ReplicaSet`.
 
-    Shares the owning engine's `ClassRegistry`, registered graphs, and
-    stack cache (read-mostly state one process can serve from), but owns
-    a PRIVATE `ExecutorCache` — executors are per-device state, so each
-    replica compiles and warms its own, and one replica's compile never
-    invalidates or evicts another's. Dispatches route through the
-    engine's ``serve_group_async`` with this view's cache injected.
+    Bound to one device. Shares the owning engine's `ClassRegistry`,
+    registered graphs, and stack cache (read-mostly state one process
+    can serve from), but owns a PRIVATE `ExecutorCache` — executors are
+    per-device state, so each replica compiles and warms its own, and
+    one replica's compile never invalidates or evicts another's.
+    Dispatches route through the engine's ``serve_group_async`` with
+    this view's cache and device injected: features, partitions and
+    weights are all placed on ``device``, so the executors run there.
     """
 
-    def __init__(self, engine: "Engine", replica_id: int, executors):
+    def __init__(self, engine: "Engine", replica_id: int, executors,
+                 device):
         self._engine = engine
         self.replica_id = replica_id
         self.executors = executors
+        self.device = device
 
     def group_key(self, name: str, x) -> tuple:
         return self._engine.group_key(name, x)
@@ -99,11 +106,12 @@ class _EngineReplicaView:
         return self._engine.latency_prior(key, batch)
 
     def prepare_x(self, name: str, x):
-        return self._engine.prepare_x(name, x)
+        return self._engine.prepare_x(name, x, device=self.device)
 
     def serve_group_async(self, requests, prepared=None) -> tuple:
         return self._engine.serve_group_async(
-            requests, prepared, executors=self.executors)
+            requests, prepared, executors=self.executors,
+            device=self.device)
 
     def serve_group(self, requests) -> list:
         return self.serve_group_async(requests)[0]
@@ -138,7 +146,8 @@ class Engine:
         # Guards the stack cache: pipelined staging workers may run
         # serve_group_async concurrently with each other and with user
         # infer() calls. Per-member padding stays outside the lock (no
-        # shared state); only the OrderedDict bookkeeping is inside.
+        # shared state); only the OrderedDict bookkeeping is inside. It
+        # also guards the handles' per-device copies (`_placed`).
         self._stack_lock = threading.Lock()
         # Stack-cache telemetry on the unified metrics registry
         # (repro.obs.metrics); the legacy int attributes survive as
@@ -237,11 +246,18 @@ class Engine:
 
     def replica_view(self, i: int) -> _EngineReplicaView:
         """The per-replica engine view a `repro.serving.ReplicaSet` lane
-        drives: shared registry and graphs, private `ExecutorCache`
-        (same backend/dispatch configuration as the engine's own).
-        Idempotent per index — a lane's cache survives re-wiring."""
+        drives: bound to ``jax.devices()[i]``, with shared registry and
+        graphs (copied to that device on first use) and a private
+        `ExecutorCache` (same backend/dispatch configuration as the
+        engine's own). Idempotent per index — a lane's cache survives
+        re-wiring."""
         view = self._replica_views.get(i)
         if view is None:
+            devices = jax.devices()
+            if not 0 <= i < len(devices):
+                raise ValueError(
+                    f"replica {i} needs its own device, but JAX sees "
+                    f"{len(devices)}")
             ex = self.executors
             cache = ExecutorCache(backend=ex.backend,
                                   block_cols=ex.block_cols,
@@ -251,12 +267,26 @@ class Engine:
             cache.injector = self.injector
             self._replica_caches.append(cache)
             view = self._replica_views[i] = _EngineReplicaView(
-                self, i, cache)
+                self, i, cache, devices[i])
         return view
 
+    def _placed(self, h: GraphHandle, device) -> tuple:
+        """``h``'s (padded partition, weights) on ``device``; None means
+        where ``register`` put them. A device's copy is made once, on
+        its first dispatch."""
+        if device is None:
+            return h.part, h.weights
+        with self._stack_lock:
+            placed = h.copies.get(device)
+            if placed is None:
+                placed = h.copies[device] = jax.device_put(
+                    (h.part, h.weights), device)
+        return placed
+
     # ---------------------------------------------------------- online -----
-    def _pad_x(self, h: GraphHandle, x) -> jnp.ndarray:
-        """Permute + zero-pad request features to the class input rows."""
+    def _pad_x(self, h: GraphHandle, x, device=None) -> jnp.ndarray:
+        """Permute + zero-pad request features to the class input rows,
+        placed on ``device`` (None: the default device)."""
         x = np.asarray(x, np.float32)
         if x.shape[0] != h.meta.n_cols:
             raise ValueError(
@@ -267,7 +297,7 @@ class Engine:
         want = h.sclass.n_col_tiles * h.sclass.tile
         if x.shape[0] != want:
             x = np.pad(x, ((0, want - x.shape[0]), (0, 0)))
-        return jnp.asarray(x)
+        return jnp.asarray(x) if device is None else jax.device_put(x, device)
 
     def _unpad_y(self, h: GraphHandle, y) -> jnp.ndarray:
         y = y[: h.n_rows]
@@ -358,17 +388,17 @@ class Engine:
         """
         return self.serve_group_async(requests)[0]
 
-    def prepare_x(self, name: str, x) -> jnp.ndarray:
+    def prepare_x(self, name: str, x, device=None) -> jnp.ndarray:
         """Stage one request's features: permute + pad to the graph's
-        class input rows and place on device. Pure per-request work with
-        no shared state, so pipelined staging workers may run it
-        concurrently; the result feeds ``serve_group_async``'s
-        ``prepared`` argument to move this cost off the ordered enqueue
-        step."""
-        return self._pad_x(self._graphs[name], x)
+        class input rows and place on ``device`` (None: the default
+        device). Pure per-request work with no shared state, so
+        pipelined staging workers may run it concurrently; the result
+        feeds ``serve_group_async``'s ``prepared`` argument to move this
+        cost off the ordered enqueue step."""
+        return self._pad_x(self._graphs[name], x, device)
 
     def serve_group_async(self, requests, prepared=None, *,
-                          executors=None) -> tuple:
+                          executors=None, device=None) -> tuple:
         """Non-blocking ``serve_group``: stage + enqueue, don't wait.
 
         Returns ``(outs, meta)``: ``outs`` are the per-request outputs
@@ -386,8 +416,10 @@ class Engine:
         ``prepared`` optionally carries pre-staged padded features
         (`prepare_x`, aligned with ``requests``) so a staging pool can
         parallelize the padding while the enqueue itself stays ordered.
-        ``executors`` substitutes a per-replica `ExecutorCache` (what
-        `replica_view` dispatches through); None uses the engine's own.
+        ``executors`` and ``device`` are a replica lane's private
+        `ExecutorCache` and device (what `replica_view` dispatches
+        through); None uses the engine's own cache and the arrays where
+        ``register`` placed them.
         """
         ex = executors if executors is not None else self.executors
         if not requests:
@@ -423,7 +455,7 @@ class Engine:
         misses0 = ex.stats.misses  # lint: racy-ok(cold-detect delta; over-reports only)
 
         def pad(h, x, xp):
-            return xp if xp is not None else self._pad_x(h, x)
+            return xp if xp is not None else self._pad_x(h, x, device)
 
         tr = self.tracer
         if len(members) == 1:
@@ -433,8 +465,9 @@ class Engine:
                 sp_pad = tr.begin("pad", "engine", args={"n": 1})
             fn = ex.gcn(sc, f_in, w_shapes)
             xpad = pad(h, x, xp)
+            part, weights = self._placed(h, device)
             tr.end(sp_pad)
-            outs = [self._unpad_y(h, fn(h.part, xpad, h.weights))]
+            outs = [self._unpad_y(h, fn(part, xpad, weights))]
             meta = self._completion_meta(outs, misses0, ex)
             if inj.enabled:
                 outs, meta = self._inject_async(inj, requests, outs, meta)
@@ -452,17 +485,19 @@ class Engine:
             sp_pad = tr.begin("pad", "engine",
                               args={"n": len(members), "batch": bs})
         fn = ex.gcn_batched(sc, f_in, w_shapes, bs)
-        stack_key = tuple(h.name for _, h, _, _ in padded)
+        # one stack per device: a lane's stack lives where it runs
+        stack_key = (device,) + tuple(h.name for _, h, _, _ in padded)
+        placed = [self._placed(h, device) for _, h, _, _ in padded]
         with self._stack_lock:
             stacks = self._stacks.get(stack_key)
             if stacks is None:
                 self._stack_misses.inc()
                 part_stack = jtu.tree_map(
                     lambda *leaves: jnp.stack(leaves),
-                    *[h.part for _, h, _, _ in padded])
+                    *[part for part, _ in placed])
                 w_stack = jtu.tree_map(
                     lambda *ws: jnp.stack(ws),
-                    *[h.weights for _, h, _, _ in padded])
+                    *[weights for _, weights in placed])
                 while len(self._stacks) >= self._max_stacks:
                     self._stacks.popitem(last=False)       # LRU out
                     self._stack_evictions.inc()
@@ -537,12 +572,14 @@ class Engine:
         overhead so an arithmetic-light class never forecasts an
         implausibly instant dispatch (which would make the scheduler
         linger past its deadline). Returns None for keys whose class
-        lacks capacity metadata (e.g. the simulation's stub classes) —
-        the model then falls back to its flat default.
+        lacks capacity metadata (e.g. the simulation's stub classes),
+        and on a device whose kind has no published peaks — the model
+        then learns from observations, starting at its flat default.
         """
-        from repro.analysis.roofline import HBM_BW, PEAK_FLOPS
+        from repro.analysis.roofline import PEAKS
         sc = key[0]
-        if not hasattr(sc, "ell_mac_capacity"):
+        peaks = PEAKS.get(jax.devices()[0].device_kind)
+        if peaks is None or not hasattr(sc, "ell_mac_capacity"):
             return None
         f_in = key[1]
         w_shapes = key[2] if len(key) > 2 else ()
@@ -554,7 +591,7 @@ class Engine:
         flops = 2.0 * macs * sum(widths)
         flops += sum(2.0 * n_rows * a * b for a, b in w_shapes)
         byts = 4.0 * (macs + n_rows * sum(widths))
-        t = max(flops / PEAK_FLOPS, byts / HBM_BW) * max(int(batch), 1)
+        t = max(flops / peaks.flops, byts / peaks.hbm_bw) * max(int(batch), 1)
         return max(t, self.LAUNCH_FLOOR_S)
 
     # Floor for the roofline prior: per-dispatch launch/host overhead no
@@ -708,6 +745,7 @@ class Engine:
             part = unpad_from_class(h.part, h.padded_meta, h.meta)
             padded, pmeta = pad_to_class(part, h.meta, target)
             h.part = jax.device_put(padded)
+            h.copies = {}       # replica copies hold the old padding
             h.padded_meta = pmeta
             h.sclass = target
             moved.append(name)

@@ -51,5 +51,6 @@ def bsr_spmm(tiles: jnp.ndarray, tile_col: jnp.ndarray,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_t, t, fp), jnp.float32),
         interpret=interpret,
+        name="bsr_spmm",
     )(tile_col, tiles, b_p)
     return out[:, :, :f]

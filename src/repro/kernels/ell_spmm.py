@@ -8,28 +8,35 @@ concatenated unit array with a per-unit mask ``kk < unit_k[u]`` —
 the B-tile choice and the live trip count are known before each grid
 step's body runs.
 
+Mosaic has no in-kernel row gather, so a unit's product is formed on
+the MXU: the unit's [R, K] slab is densified on the VPU into its
+[R, T] row block of A (a one-hot compare per slab column), which then
+multiplies the unit's [T, bf] B tile at HIGHEST precision (the f32
+gather it replaces is exact, and so stays the densified product).
+
 v2 grid structure (density-aware):
 
   * **K bands** — units arrive sorted by K descending (the partition
     emits them that way; ``segments`` carries the (K, n_units) runs).
     The runs are merged to at most ``max_bands`` bands and the kernel
-    selects, per grid step, the FMA chain of that step's band via
-    ``lax.switch`` — short units stop paying the full-Kmax trip count.
-    Each unit's whole accumulation chain still runs inside one body
-    execution (band chains only drop trips the value mask already
-    zeroed), so live lanes stay bitwise-identical to the fixed-K path.
+    selects, per unit, the densify chain of its band via ``lax.switch``
+    — short units stop paying the full-Kmax trip count. The densified
+    block does not depend on the chain length (the band chains only
+    drop slab columns the value mask already zeroed), so every band
+    plan is bitwise-identical to the fixed-K path.
   * **Unit batching** (``gu > 1``) — process ``gu`` units per grid step
     against the whole padded B resident in VMEM (block index maps drop
-    the per-unit ``tile_col`` lookup; rows are gathered at global index
-    ``tile_col*T + col``). Cuts grid steps — and their fixed overhead —
-    by ``gu``× at the cost of ``nct*T*bf`` VMEM for B, so it is only
-    legal for small graphs: the default resolves via ``auto_gu`` (the
-    largest VMEM-legal batch), the autotuner proposes overrides, and
-    the kernel contract oracle (``repro.analysis.static.kernel_pass``)
-    rejects any candidate whose working set blows the VMEM budget.
+    the per-unit ``tile_col`` lookup; each unit reads its B tile at a
+    scalar ``tile_col`` from SMEM). Cuts grid steps — and their fixed
+    overhead — by ``gu``× at the cost of ``nct*T*bf`` VMEM for B, so it
+    is only legal for small graphs: the default resolves via
+    ``auto_gu`` (the largest VMEM-legal batch), the autotuner proposes
+    overrides, and the kernel contract oracle
+    (``repro.analysis.static.kernel_pass``) rejects any candidate whose
+    working set blows the VMEM budget.
   * **Multi-buffering** (``buffer_depth``) — the contract carries the
     HBM→VMEM pipeline depth and ``dimension_semantics`` so DMA for grid
-    step i+1 overlaps step i's FMA chain; the feature axis is declared
+    step i+1 overlaps step i's compute; the feature axis is declared
     ``parallel`` (steps independent), the unit axis ``arbitrary``.
 
 The legacy fixed-K kernel (``ell_spmm``) is retained for the
@@ -57,7 +64,9 @@ DEFAULT_MAX_BANDS = 4
 # HBM->VMEM pipeline depth (double-buffered by default, quad is the
 # autotuner's other legal choice).
 DEFAULT_BUFFER_DEPTH = 2
-# VMEM budget the contracts are audited against (one core's VMEM).
+# Scoped-VMEM limit every ELL launch passes to Mosaic
+# (``vmem_limit_bytes``), and the budget the kernel pass audits the
+# contracts' multi-buffered working sets against.
 VMEM_BUDGET_BYTES = 16 * 2 ** 20
 
 
@@ -113,18 +122,17 @@ def _bands_of(segments, u: int, kmax: int, max_bands: int) -> tuple:
 
 
 def _band_tables(bands) -> tuple:
-    """(band_ks, band_counts, band_offs): static switch tables.
+    """(band_ks, band_offs): static switch tables.
 
     ``band_offs`` holds the starting unit index of every band past the
     first; the kernel's band selector is ``sum(i >= off)``.
     """
     band_ks = tuple(k for k, _ in bands)
-    band_counts = tuple(n for _, n in bands)
     offs, at = [], 0
     for _, n in bands[:-1]:
         at += n
         offs.append(at)
-    return band_ks, band_counts, tuple(offs)
+    return band_ks, tuple(offs)
 
 
 def _spec_block_bytes(specs, elem_bytes: int) -> int:
@@ -155,7 +163,6 @@ def ell_contract(u: int, r: int, k: int, nct: int, t: int, f: int,
         pl.BlockSpec((1, t, bf_), lambda i, j, tc: (tc[i], 0, j)),
     ]
     out_specs = [pl.BlockSpec((1, r, bf_), lambda i, j, tc: (i, 0, j))]
-    block_bytes = _spec_block_bytes(in_specs + out_specs, 4)
     return {
         "name": "ell_spmm",
         "grid": (u, fp // bf_),
@@ -168,8 +175,7 @@ def ell_contract(u: int, r: int, k: int, nct: int, t: int, f: int,
         "elem_bytes": 4,
         "buffer_depth": buffer_depth,
         "dimension_semantics": ("arbitrary", "parallel"),
-        "vmem_limit_bytes": max(VMEM_BUDGET_BYTES,
-                                block_bytes * buffer_depth),
+        "vmem_limit_bytes": VMEM_BUDGET_BYTES,
     }
 
 
@@ -193,7 +199,7 @@ def ragged_ell_contract(u: int, r: int, kmax: int, nct: int, t: int, f: int,
         raise ValueError(f"buffer_depth must be >= 1, got {buffer_depth}")
     bf_, fp = _pad_f(f, bf)
     bands = _bands_of(segments, u, kmax, max_bands)
-    band_ks, band_counts, band_offs = _band_tables(bands)
+    band_ks, band_offs = _band_tables(bands)
     if gu == 1:
         up = u
         grid = (u, fp // bf_)
@@ -207,8 +213,8 @@ def ragged_ell_contract(u: int, r: int, kmax: int, nct: int, t: int, f: int,
     else:
         # gu units per step against the WHOLE padded B in VMEM: the
         # B block ignores the unit axis (index maps can't read gu
-        # different tile_cols), so rows are gathered at global index
-        # tile_col*T + col inside the body.
+        # different tile_cols), so each unit picks its tile inside the
+        # body.
         up = -(-u // gu) * gu
         grid = (up // gu, fp // bf_)
         in_specs = [
@@ -218,7 +224,6 @@ def ragged_ell_contract(u: int, r: int, kmax: int, nct: int, t: int, f: int,
         ]
         out_specs = [pl.BlockSpec((gu, r, bf_),
                                   lambda i, j, tc, ks: (i, 0, j))]
-    block_bytes = _spec_block_bytes(in_specs + out_specs, 4)
     return {
         "name": "ragged_ell_spmm",
         "grid": grid,
@@ -231,26 +236,25 @@ def ragged_ell_contract(u: int, r: int, kmax: int, nct: int, t: int, f: int,
         "elem_bytes": 4,
         "segments": tuple((int(k), int(n)) for k, n in segments),
         "band_ks": band_ks,
-        "band_counts": band_counts,
         "band_offs": band_offs,
         "buffer_depth": buffer_depth,
         "gu": gu,
         "dimension_semantics": ("arbitrary", "parallel"),
-        "vmem_limit_bytes": max(VMEM_BUDGET_BYTES,
-                                block_bytes * buffer_depth),
+        "vmem_limit_bytes": VMEM_BUDGET_BYTES,
     }
 
 
 def contract_cost(c: dict) -> dict:
-    """Analytic per-launch cost of a contract: HBM bytes + FMA FLOPs.
+    """Analytic per-launch cost of a contract: HBM bytes + MXU FLOPs.
 
     ``hbm_bytes`` counts every block the grid moves (in + out, once per
     step — multi-buffering overlaps the transfers, it does not remove
-    them); ``flops`` counts the band chains actually executed (2 ops
-    per MAC over r×bf lanes per trip). Benchmarks divide these by the
-    roofline constants to report the DMA-vs-compute split and the
-    achieved-roofline fraction; this module deliberately knows bytes
-    and FLOPs only.
+    them); ``flops`` counts the MXU products the kernel issues, one
+    [R, T] x [T, F] product per (padded) unit whatever its K (2 ops per
+    MAC; the K bands shorten only the VPU densify, not counted here).
+    Benchmarks divide these by the roofline constants to report the
+    DMA-vs-compute split and the achieved-roofline fraction; this
+    module deliberately knows bytes and FLOPs only.
     """
     n_steps = 1
     for g in c["grid"]:
@@ -258,26 +262,9 @@ def contract_cost(c: dict) -> dict:
     step_bytes = _spec_block_bytes(
         list(c["in_specs"]) + list(c["out_specs"]), c["elem_bytes"])
     hbm_bytes = step_bytes * n_steps
-    out_block = c["out_specs"][0].block_shape        # (gu, r, bf_)
-    gu = int(c.get("gu", 1))
-    rows = int(out_block[-2])
-    bf_ = int(out_block[-1])
-    band_ks = c.get("band_ks", ())
-    band_counts = c.get("band_counts", ())
-    if band_ks:
-        # grid steps along the unit axis per band (gu units per step;
-        # a step straddling a band boundary runs the wider chain)
-        trips = 0
-        at = 0
-        for k, n in zip(band_ks, band_counts):
-            lo, hi = at, at + n
-            steps = -(-hi // gu) - lo // gu
-            trips += k * steps
-            at = hi
-    else:
-        trips = 0
-    f_blocks = int(c["grid"][-1])
-    flops = 2.0 * trips * f_blocks * gu * rows * bf_
+    units, rows, _ = c["in_shapes"][0]               # (up, r, k)
+    _, t, fp = c["in_shapes"][2]                     # (nct, t, fp)
+    flops = 2.0 * units * rows * t * fp
     return {"hbm_bytes": float(hbm_bytes), "flops": flops}
 
 
@@ -305,16 +292,35 @@ def auto_gu(u: int, r: int, kmax: int, nct: int, t: int, f: int,
     return 1
 
 
+def _unit_product(cols, vals, b, k: int, ku=None):
+    """One unit's [R, bf] product over its first ``k`` slab columns.
+
+    Mosaic has no in-kernel row gather, so the unit is densified on the
+    VPU into its [R, T] row block (column ``cols[:, kk]`` of row r gets
+    ``vals[r, kk]``) and multiplied with the B tile on the MXU. Rows of
+    an ELL slab hold distinct columns and padding is value-zero, so the
+    densified block is exact and independent of K; HIGHEST precision
+    keeps the f32 product free of bf16 input rounding. ``ku`` (the
+    unit's live K) masks the VALUES: lanes at or past it contribute
+    nothing, whatever the slab holds there.
+    """
+    iota = jax.lax.broadcasted_iota(jnp.int32, (cols.shape[0], b.shape[0]),
+                                    1)
+    dense = jnp.zeros(iota.shape, jnp.float32)
+    for kk in range(k):                              # static trip count
+        v = vals[:, kk:kk + 1]                       # [R, 1]
+        if ku is not None:
+            v = jnp.where(kk < ku, v, 0.0)
+        dense = dense + jnp.where(cols[:, kk:kk + 1] == iota, v, 0.0)
+    return jnp.dot(dense, b.astype(jnp.float32),
+                   precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=jnp.float32)
+
+
 def _ell_kernel(tile_col_ref, cols_ref, vals_ref, b_ref, o_ref, *, k: int):
     del tile_col_ref  # consumed by the index maps
-    b = b_ref[0]                                     # [T, bf]
-    cols = cols_ref[0]                               # [R, K]
-    vals = vals_ref[0].astype(jnp.float32)           # [R, K]
-    acc = jnp.zeros((cols.shape[0], b.shape[1]), jnp.float32)
-    for kk in range(k):                              # static trip count
-        g = jnp.take(b, cols[:, kk], axis=0)         # [R, bf] row gather
-        acc = acc + vals[:, kk][:, None] * g.astype(jnp.float32)
-    o_ref[0] = acc
+    o_ref[0] = _unit_product(cols_ref[0], vals_ref[0].astype(jnp.float32),
+                             b_ref[0], k)
 
 
 @functools.partial(jax.jit, static_argnames=("bf", "buffer_depth",
@@ -345,6 +351,7 @@ def ell_spmm(cols: jnp.ndarray, vals: jnp.ndarray, tile_col: jnp.ndarray,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(c["out_shapes"][0], jnp.float32),
         interpret=interpret,
+        name=c["name"],
         **_compiler_kw(c, interpret),
     )(tile_col, cols, vals, b_p)
     return out[:, :, :f]
@@ -355,76 +362,45 @@ def _compiler_kw(c: dict, interpret: bool) -> dict:
     interpret mode takes no compiler params)."""
     if interpret:
         return {}
-    return {"compiler_params": pltpu.TPUCompilerParams(
+    return {"compiler_params": pltpu.CompilerParams(
         dimension_semantics=c["dimension_semantics"],
         vmem_limit_bytes=c["vmem_limit_bytes"])}
 
 
 def _ragged_ell_kernel(tile_col_ref, unit_k_ref, cols_ref, vals_ref, b_ref,
-                       o_ref, *, band_ks: tuple, band_offs: tuple,
-                       gu: int, t: int):
-    """Band-switched masked FMA over gu units per grid step.
+                       o_ref, *, band_ks: tuple, band_offs: tuple, gu: int):
+    """Band-switched masked unit products over gu units per grid step.
 
-    Every unit's full accumulation chain runs inside this one body
-    execution (its band K bounds its unit_k), so live lanes are
-    bitwise-identical to the fixed-K kernel: the mask sits on the
-    VALUES and band chains only drop trips the mask already zeroed.
+    Every unit's whole product runs inside this one body execution, at
+    the K of its own band (units are K-descending, so the band bounds
+    its unit_k), so each unit's result is independent of ``gu``, the
+    band plan and the feature blocking: every launch configuration is
+    bitwise-equal to every other and to the fixed-K kernel.
     """
     i = pl.program_id(0)
+
+    def unit(g, b):
+        """Unit ``i*gu + g`` against its [T, bf] B tile ``b``."""
+        at = i * gu + g
+        ku = unit_k_ref[at]                          # this unit's live K
+        cols = cols_ref[g]                           # [R, Kmax]
+        vals = vals_ref[g].astype(jnp.float32)       # [R, Kmax]
+        chains = [functools.partial(_unit_product, cols, vals, b, k, ku)
+                  for k in band_ks]
+        if len(chains) == 1:
+            return chains[0]()
+        band = sum((at >= off).astype(jnp.int32) for off in band_offs)
+        return jax.lax.switch(band, chains)
+
     if gu == 1:
         del tile_col_ref  # consumed by the index maps
-        ku = unit_k_ref[i]                           # this unit's live K
-        b = b_ref[0]                                 # [T, bf]
-        cols = cols_ref[0]                           # [R, Kmax]
-        vals = vals_ref[0].astype(jnp.float32)       # [R, Kmax]
-
-        def chain(k):
-            def run():
-                acc = jnp.zeros((cols.shape[0], b.shape[1]), jnp.float32)
-                for kk in range(k):                  # static trip count
-                    g = jnp.take(b, cols[:, kk], axis=0)
-                    # Mask the VALUES, not the product: the FMA then has
-                    # the exact expression shape of the fixed-K kernel.
-                    v = jnp.where(kk < ku, vals[:, kk], 0.0)
-                    acc = acc + v[:, None] * g.astype(jnp.float32)
-                return acc
-            return run
-
-        if len(band_ks) == 1:
-            o_ref[0] = chain(band_ks[0])()
-        else:
-            band = sum(jnp.int32(i >= off) for off in band_offs)
-            o_ref[0] = jax.lax.switch(band, [chain(k) for k in band_ks])
+        o_ref[0] = unit(0, b_ref[0])
         return
 
-    # gu > 1: whole padded B is resident; gather at global row index
-    # tile_col*T + col. The step's chain is its FIRST unit's band (units
-    # are K-descending, so that bounds every unit_k in the step).
-    ku = unit_k_ref[pl.ds(i * gu, gu)]               # [gu]
-    tc = tile_col_ref[pl.ds(i * gu, gu)]             # [gu]
-    bf_ = b_ref.shape[2]
-    bflat = b_ref[...].reshape(-1, bf_)              # [nct*T, bf]
-    cols = cols_ref[...]                             # [gu, R, Kmax]
-    vals = vals_ref[...].astype(jnp.float32)         # [gu, R, Kmax]
-    base = tc * t                                    # [gu]
-
-    def chain(k):
-        def run():
-            acc = jnp.zeros((cols.shape[0], cols.shape[1], bf_),
-                            jnp.float32)
-            for kk in range(k):                      # static trip count
-                g = jnp.take(bflat, base[:, None] + cols[:, :, kk],
-                             axis=0)                 # [gu, R, bf]
-                v = jnp.where(kk < ku[:, None], vals[:, :, kk], 0.0)
-                acc = acc + v[:, :, None] * g.astype(jnp.float32)
-            return acc
-        return run
-
-    if len(band_ks) == 1:
-        o_ref[...] = chain(band_ks[0])()
-    else:
-        band = sum(jnp.int32(i * gu >= off) for off in band_offs)
-        o_ref[...] = jax.lax.switch(band, [chain(k) for k in band_ks])
+    # gu > 1: the whole padded B is resident; each unit reads its own
+    # tile with a scalar tile_col read (SMEM holds scalars only).
+    for g in range(gu):
+        o_ref[g] = unit(g, b_ref[tile_col_ref[i * gu + g]])
 
 
 @functools.partial(jax.jit, static_argnames=("bf", "segments", "max_bands",
@@ -478,10 +454,11 @@ def ragged_ell_spmm(cols: jnp.ndarray, vals: jnp.ndarray,
     )
     out = pl.pallas_call(
         functools.partial(_ragged_ell_kernel, band_ks=c["band_ks"],
-                          band_offs=c["band_offs"], gu=gu, t=t),
+                          band_offs=c["band_offs"], gu=gu),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(c["out_shapes"][0], jnp.float32),
         interpret=interpret,
+        name=c["name"],
         **_compiler_kw(c, interpret),
     )(tile_col, unit_k, cols, vals, b_p)
     return out[:u, :, :f]

@@ -84,5 +84,6 @@ def tile_matmul(a: jnp.ndarray, b: jnp.ndarray, *, bm: int = DEFAULT_BM,
         out_shape=jax.ShapeDtypeStruct(c["out_shapes"][0], a.dtype),
         scratch_shapes=c["scratch_shapes"],
         interpret=interpret,
+        name=c["name"],
     )(a_p, b_p)
     return out[:m, :n]

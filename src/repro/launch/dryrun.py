@@ -19,7 +19,7 @@ import traceback
 
 import jax
 
-from repro.analysis.roofline import analyze_compiled
+from repro.analysis.roofline import V5E, analyze_compiled
 from repro.configs import ASSIGNED, get_arch
 from repro.configs.base import TransformerConfig
 from repro.launch.mesh import make_production_mesh
@@ -42,8 +42,7 @@ def _lower_compile(prog, mesh):
 
 def _probe_terms(compiled):
     from repro.analysis.hlo import collective_summary
-    from repro.analysis.roofline import merge_cost_analysis
-    ca = merge_cost_analysis(compiled.cost_analysis())
+    ca = compiled.cost_analysis()
     return (float(ca.get("flops", 0.0)),
             float(ca.get("bytes accessed", 0.0)),
             float(collective_summary(compiled.as_text())
@@ -62,8 +61,9 @@ def run_cell(arch_name: str, cell_name: str, *, multi_pod: bool = False,
     t_compile = time.perf_counter() - t0
     t_lower = 0.0
 
+    # the production meshes model v5e pods (16x16 chips per pod)
     roof = analyze_compiled(arch_name, cell_name, mesh_name, chips,
-                            compiled, prog.model_flops)
+                            compiled, prog.model_flops, device_kind=V5E)
 
     # --- scan-cost correction (LM cells): XLA cost_analysis counts a
     # while-loop body once, so a scanned L-layer program under-reports by

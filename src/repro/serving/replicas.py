@@ -86,12 +86,10 @@ class Replica:
 
 
 def _device_count() -> int:
-    """Default replica count: one per visible JAX device."""
-    try:
-        import jax
-        return max(1, len(jax.devices()))
-    except Exception:              # noqa: BLE001 — headless/no-jax envs
-        return 1
+    """Default replica count: one per visible JAX device (raises where
+    JAX finds no backend, rather than guess one)."""
+    import jax
+    return len(jax.devices())
 
 
 class ReplicaSet:

@@ -25,13 +25,14 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
+from repro.launch.mesh import make_mesh
 """
 
 
 def test_halo_ops_match_oracle():
     out = run_in_subprocess(HEADER + textwrap.dedent("""
         from repro.distributed.halo import make_halo_ops
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         take, seg = make_halo_ops(mesh, ("data", "model"))
         n, m, d, shard = 64, 48, 5, 8
         rng = np.random.default_rng(0)
@@ -61,10 +62,9 @@ def test_small_mesh_dryrun_lm_and_fm():
     """A miniature multi-device dry-run: lower+compile two full-config
     cells on a 4x2 mesh and check roofline extraction works."""
     out = run_in_subprocess(HEADER + textwrap.dedent("""
-        from repro.launch.mesh import make_mesh
         from repro.launch.specs import build_cell
         from repro.distributed.sharding import to_named
-        from repro.analysis.roofline import analyze_compiled
+        from repro.analysis.roofline import V5E, analyze_compiled
         mesh = make_mesh((4, 2), ("data", "model"))
         for arch, cell in [("smollm-360m", "train_4k"), ("fm", "serve_p99"),
                            ("gatedgcn", "full_graph_sm")]:
@@ -75,7 +75,8 @@ def test_small_mesh_dryrun_lm_and_fm():
                                            if prog.out_specs is not None else None),
                             donate_argnums=prog.donate or ()) \\
                     .lower(*prog.args).compile()
-            r = analyze_compiled(arch, cell, "4x2", 8, c, prog.model_flops)
+            r = analyze_compiled(arch, cell, "4x2", 8, c, prog.model_flops,
+                                 device_kind=V5E)
             assert r.hlo_flops > 0 and r.t_bound > 0
             print("CELL_OK", arch, cell, r.bottleneck)
         """))
@@ -88,7 +89,7 @@ def test_lm_param_shardings_cover_fsdp():
         from repro.configs import get_arch
         from repro.distributed import sharding as shd
         from repro.models import transformer as T
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         cfg = get_arch("granite-8b").config
         structs = jax.eval_shape(lambda k: T.init_params(cfg, k),
                                  jax.random.PRNGKey(0))
@@ -112,7 +113,7 @@ def test_elastic_reshard():
     4-device mesh (device loss) without value change."""
     out = run_in_subprocess(HEADER + textwrap.dedent("""
         from repro.launch.elastic import reshard_to_mesh
-        mesh8 = jax.make_mesh((4, 2), ("data", "model"))
+        mesh8 = make_mesh((4, 2), ("data", "model"))
         devs = np.asarray(jax.devices()[:4]).reshape(2, 2)
         from jax.sharding import Mesh
         mesh4 = Mesh(devs, ("data", "model"))
@@ -136,7 +137,7 @@ def test_moe_ep_dispatch_matches_dense_mixture():
         from repro.configs import get_arch
         from repro.models import transformer as T
         from repro.models.moe_ep import moe_ffn_ep
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         cfg = dataclasses.replace(get_arch("qwen3-moe-235b-a22b").smoke,
                                   n_experts=8, top_k=2, capacity_factor=8.0)
         lp = T.init_layer_params(cfg, jax.random.PRNGKey(0))

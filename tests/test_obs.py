@@ -482,8 +482,10 @@ class TestSpanTreeProperty:
                              admission=AdmissionPolicy(max_depth=max_depth),
                              tracer=tracer)
         trace = bursty_trace(n_bursts, burst, 0.5, names, seed=seed)
-        replay_trace(queue, trace, xs.__getitem__)
-        rejected = 0
+        # the trace itself can overrun max_depth: its rejections are
+        # submissions too, each with its own root span
+        _, trace_rejected = replay_trace(queue, trace, xs.__getitem__)
+        rejected = sum(trace_rejected)
         for _ in range(flood):      # no pumping: may exceed max_depth
             try:
                 queue.submit(names[0], xs[names[0]])

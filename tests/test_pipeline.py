@@ -222,6 +222,15 @@ class TestLatencySegments:
 
 
 class TestRooflinePrior:
+    @staticmethod
+    def _price_host_as_v5e(monkeypatch):
+        """Give this host's device kind the v5e peaks, so the prior can
+        be checked on a CPU (which has no published peaks)."""
+        import jax
+        from repro.analysis import roofline
+        monkeypatch.setitem(roofline.PEAKS, jax.devices()[0].device_kind,
+                            roofline.peaks_for(roofline.V5E))
+
     def _engine(self):
         from repro.core import csr_from_dense
         from repro.engine import Engine
@@ -234,7 +243,8 @@ class TestRooflinePrior:
         x = rng.standard_normal((300, 16)).astype(np.float32)
         return eng, x
 
-    def test_prior_seeds_unseen_key_and_data_overrides(self):
+    def test_prior_seeds_unseen_key_and_data_overrides(self, monkeypatch):
+        self._price_host_as_v5e(monkeypatch)
         eng, x = self._engine()
         key = eng.group_key("g0", x)
         m = LatencyModel(default_s=0.05, prior=eng.latency_prior)
@@ -246,11 +256,24 @@ class TestRooflinePrior:
         assert m.estimate(key, 1) == pytest.approx(0.123), \
             "an observation must beat the prior"
 
-    def test_prior_scales_with_batch_and_floors(self):
+    def test_prior_scales_with_batch_and_floors(self, monkeypatch):
+        self._price_host_as_v5e(monkeypatch)
         eng, x = self._engine()
         key = eng.group_key("g0", x)
         t1, t8 = eng.latency_prior(key, 1), eng.latency_prior(key, 8)
         assert t8 >= t1 >= eng.LAUNCH_FLOOR_S
+
+    def test_unknown_device_kind_has_no_prior(self, monkeypatch):
+        import jax
+        from repro.analysis import roofline
+        kind = jax.devices()[0].device_kind
+        monkeypatch.delitem(roofline.PEAKS, kind, raising=False)
+        eng, x = self._engine()
+        key = eng.group_key("g0", x)
+        assert eng.latency_prior(key, 1) is None, \
+            f"no peaks are published for {kind!r}: the model must learn"
+        m = LatencyModel(default_s=0.05, prior=eng.latency_prior)
+        assert m.estimate(key, 1) == 0.05 and m.prior_hits == 0
 
     def test_stub_classes_fall_through_to_default(self):
         clock = SimClock()

@@ -1,9 +1,8 @@
-"""`cost_analysis()` normalization: newer JAX returns a list of dicts
-(one per executable module), older JAX a single dict. Both must flow
-through `analyze_compiled` without touching a real compiled artifact."""
+"""Roofline terms from `Compiled.cost_analysis()`, priced on the peaks
+of a named device kind (a kind without published peaks is an error)."""
 import pytest
 
-from repro.analysis.roofline import analyze_compiled, merge_cost_analysis
+from repro.analysis.roofline import V5E, analyze_compiled, peaks_for
 
 
 class FakeCompiled:
@@ -23,34 +22,12 @@ class FakeCompiled:
 
 
 CA_DICT = {"flops": 1024.0, "bytes accessed": 768.0, "utilization0{}": 1.0}
-CA_LIST = [{"flops": 1024.0, "bytes accessed": 768.0, "utilization0{}": 1.0}]
 
 
-class TestMergeCostAnalysis:
-    def test_dict_passthrough(self):
-        assert merge_cost_analysis(CA_DICT) == CA_DICT
-
-    def test_single_element_list(self):
-        assert merge_cost_analysis(CA_LIST) == CA_DICT
-
-    def test_multi_module_sums_numeric(self):
-        ca = [{"flops": 10.0, "bytes accessed": 5.0},
-              {"flops": 3.0, "tag": "x"}]
-        merged = merge_cost_analysis(ca)
-        assert merged["flops"] == 13.0
-        assert merged["bytes accessed"] == 5.0
-        assert merged["tag"] == "x"
-
-    def test_degenerate(self):
-        assert merge_cost_analysis(None) == {}
-        assert merge_cost_analysis([]) == {}
-        assert merge_cost_analysis([None, {}]) == {}
-
-
-@pytest.mark.parametrize("ca", [CA_DICT, CA_LIST], ids=["dict", "list"])
-def test_analyze_compiled_both_shapes(ca):
-    roof = analyze_compiled("arch", "cell", "16x16", 256, FakeCompiled(ca),
-                            model_flops=512.0)
+def test_analyze_compiled_from_cost_analysis():
+    roof = analyze_compiled("arch", "cell", "16x16", 256,
+                            FakeCompiled(CA_DICT), model_flops=512.0,
+                            device_kind=V5E)
     assert roof.hlo_flops == 1024.0
     assert roof.hlo_bytes == 768.0
     assert roof.collective_bytes == 0.0
@@ -64,5 +41,18 @@ def test_analyze_compiled_real_jit():
     import jax.numpy as jnp
     compiled = jax.jit(lambda x: x @ x).lower(jnp.ones((8, 8))).compile()
     roof = analyze_compiled("arch", "cell", "1x1", 1, compiled,
-                            model_flops=2 * 8 * 8 * 8)
+                            model_flops=2 * 8 * 8 * 8, device_kind=V5E)
     assert roof.hlo_flops > 0
+
+
+def test_v5e_peaks_are_the_published_ones():
+    p = peaks_for(V5E)
+    assert (p.flops, p.hbm_bw) == (197e12, 819e9)
+
+
+def test_unknown_device_kind_is_an_error():
+    with pytest.raises(KeyError, match="no published peaks"):
+        peaks_for("cpu")
+    with pytest.raises(KeyError):
+        analyze_compiled("arch", "cell", "1x1", 1, FakeCompiled(CA_DICT),
+                         model_flops=1.0, device_kind="cpu")

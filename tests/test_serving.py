@@ -353,7 +353,7 @@ class TestEngineSatellites:
         assert eng.stack_hits == 1 and eng.stack_misses == 2
         eng.serve_group(pair("g1", "g2"))     # C founded -> evict LRU=B
         assert eng.stack_evictions == 1
-        keys = set(eng._stacks)
+        keys = {k[1:] for k in eng._stacks}    # drop the device slot
         assert ("g0", "g1") in keys, \
             "FIFO would have evicted the hottest stack A; LRU must keep it"
         assert ("g0", "g2") not in keys

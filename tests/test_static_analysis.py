@@ -64,7 +64,7 @@ class TestJaxprPass:
         findings = check_single_launch(closed, n_layers=len(h.weights))
         assert "single-launch" in _rules(findings)
         # and the messages name the per-K kernels it traced instead
-        assert any("_ell_kernel" in f.message for f in _errors(findings))
+        assert any("'ell_spmm'" in f.message for f in _errors(findings))
 
     def test_unmasked_kernel_fails_dead_lane_proof(self):
         # the same launch contract as the production ragged kernel, but
@@ -91,7 +91,7 @@ class TestJaxprPass:
         call = pl.pallas_call(
             functools.partial(unmasked, kmax=kmax), grid_spec=spec,
             out_shape=jax.ShapeDtypeStruct(c["out_shapes"][0], jnp.float32),
-            interpret=True)
+            interpret=True, name="unmasked_ragged_ell")
         closed = jax.make_jaxpr(call)(
             jnp.zeros(u, jnp.int32), jnp.zeros(u, jnp.int32),
             jnp.zeros((u, r, kmax), jnp.int32),
@@ -105,7 +105,7 @@ class TestJaxprPass:
         engine = fixture_engine(backend="pallas")
         closed, _ = trace_gcn_executor(engine, "lint-fixture")
         ragged = [e for e in pallas_eqns(closed)
-                  if "_ragged_ell_kernel" in kernel_name(e)]
+                  if kernel_name(e) == "ragged_ell_spmm"]
         assert ragged, "fixture must trace a ragged launch"
         assert check_dead_lanes(ragged[0]) == []
 

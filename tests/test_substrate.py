@@ -299,6 +299,31 @@ class TestDataStreams:
         assert (b["idx"] < np.array([100, 50, 10])).all()
         assert set(np.unique(b["labels"])) <= {0.0, 1.0}
 
+    def test_paper_dataset_identical_across_processes(self):
+        # Python salts str hashes per process; two processes with
+        # different salts must still synthesize the same pubmed
+        import subprocess
+        import sys
+        code = ("import hashlib\n"
+                "from repro.data.graphs import make_paper_dataset\n"
+                "a, x, y, _ = make_paper_dataset('pubmed', seed=0)\n"
+                "h = hashlib.sha256()\n"
+                "for arr in (a.indptr, a.indices, a.data, x, y):\n"
+                "    h.update(arr.tobytes())\n"
+                "print(h.hexdigest())\n")
+        src = os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "src")
+        digests = set()
+        for salt in ("1", "2"):
+            env = dict(os.environ, PYTHONHASHSEED=salt, PYTHONPATH=src,
+                       JAX_PLATFORMS="cpu")
+            out = subprocess.run([sys.executable, "-c", code], env=env,
+                                 capture_output=True, text=True,
+                                 timeout=300)
+            assert out.returncode == 0, out.stderr[-2000:]
+            digests.add(out.stdout.strip())
+        assert len(digests) == 1, digests
+
 
 class TestCompression:
     def test_quantize_roundtrip_error_bounded(self):
