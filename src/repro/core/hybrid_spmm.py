@@ -126,15 +126,22 @@ def hybrid_spmm(part: TriPartition, b: jnp.ndarray, *, meta: PartitionMeta,
     """
     if backend == "pallas":
         from repro.kernels import ops as kops
-        yd = kops.dense_tiles_matmul(part, b, meta)
-        ye = kops.ell_matmul(part, b, meta, dispatch=ell_dispatch,
-                             ell_tune=ell_tune)
+        dense = kops.dense_tiles_matmul
+        ell = functools.partial(kops.ell_matmul, dispatch=ell_dispatch,
+                                ell_tune=ell_tune)
     elif backend == "xla":
-        yd = dense_tiles_matmul(part, b, meta)
-        ye = ell_matmul(part, b, meta, dispatch=ell_dispatch)
+        dense = dense_tiles_matmul
+        ell = functools.partial(ell_matmul, dispatch=ell_dispatch)
     else:
         raise ValueError(f"unknown backend {backend!r}")
-    yc = coo_matmul(part, b, meta)
+    # One named scope per engine, whatever the backend: the device
+    # trace's ops carry them (docs/TRACING.md, "Named scopes").
+    with jax.named_scope("agg.dense"):
+        yd = dense(part, b, meta)
+    with jax.named_scope("agg.ell"):
+        ye = ell(part, b, meta)
+    with jax.named_scope("agg.coo"):
+        yc = coo_matmul(part, b, meta)
     y = yd.astype(jnp.float32) + ye + yc
     return y[: meta.n_rows].astype(b.dtype)
 
@@ -171,13 +178,16 @@ def gcn_layer(part: TriPartition, x: jnp.ndarray, w: jnp.ndarray, *,
         for i in range(nblk):  # static unroll: each block is independent
             wi = jax.lax.slice_in_dim(wp, i * block_cols, (i + 1) * block_cols,
                                       axis=1)
-            bi = x @ wi                                   # combination (dense)
+            with jax.named_scope("combine"):
+                bi = x @ wi                               # combination (dense)
             outs.append(hybrid_spmm(part, bi, meta=meta, backend=backend,
                                     ell_dispatch=ell_dispatch,
                                     ell_tune=ell_tune))
         y = jnp.concatenate(outs, axis=1)[:, :h]
     else:
-        y = hybrid_spmm(part, x @ w, meta=meta, backend=backend,
+        with jax.named_scope("combine"):
+            xw = x @ w
+        y = hybrid_spmm(part, xw, meta=meta, backend=backend,
                         ell_dispatch=ell_dispatch, ell_tune=ell_tune)
     return activation(y) if activation is not None else y
 

@@ -158,6 +158,8 @@ class Engine:
         self._stack_misses = Counter("engine.stack_misses", self.metrics)
         self._stack_evictions = Counter("engine.stack_evictions",
                                         self.metrics)
+        # Bytes of padded request features `_pad_x` put on a device.
+        self._h2d_bytes = Counter("engine.h2d_bytes", self.metrics)
         # Request tracer (repro.obs.trace): off by default; a serving
         # frontend constructed with `tracer=` calls `attach_tracer`,
         # which also fans the tracer out to the executor cache and the
@@ -292,17 +294,25 @@ class Engine:
             raise ValueError(
                 f"request features have {x.shape[0]} rows; graph "
                 f"{h.name!r} expects {h.meta.n_cols}")
-        if h.perm is not None:
-            x = x[h.perm]
-        want = h.sclass.n_col_tiles * h.sclass.tile
-        if x.shape[0] != want:
-            x = np.pad(x, ((0, want - x.shape[0]), (0, 0)))
-        return jnp.asarray(x) if device is None else jax.device_put(x, device)
+        tr = self.tracer
+        with tr.span("pad", "engine"):
+            if h.perm is not None:
+                x = x[h.perm]
+            want = h.sclass.n_col_tiles * h.sclass.tile
+            if x.shape[0] != want:
+                x = np.pad(x, ((0, want - x.shape[0]), (0, 0)))
+        # PjRt starts its host transpose into the device layout here.
+        with tr.span("h2d", "engine", {"bytes": x.nbytes}):
+            xd = (jnp.asarray(x) if device is None
+                  else jax.device_put(x, device))
+        self._h2d_bytes.inc(x.nbytes)
+        return xd
 
     def _unpad_y(self, h: GraphHandle, y) -> jnp.ndarray:
-        y = y[: h.n_rows]
-        if h.inv_perm is not None:
-            y = y[h.inv_perm]
+        with self.tracer.span("unpad", "engine"):
+            y = y[: h.n_rows]
+            if h.inv_perm is not None:
+                y = y[h.inv_perm]
         return y
 
     def spmm(self, name: str, b) -> jnp.ndarray:
@@ -341,8 +351,11 @@ class Engine:
         if h.weights is None:
             raise ValueError(f"graph {name!r} registered without weights")
         w_shapes = tuple(tuple(w.shape) for w in h.weights)
-        fn = self.executors.gcn(h.sclass, int(x.shape[1]), w_shapes)
-        return self._unpad_y(h, fn(h.part, self._pad_x(h, x), h.weights))
+        xp = self._pad_x(h, x)
+        with self.tracer.span("launch", "engine"):
+            fn = self.executors.gcn(h.sclass, int(x.shape[1]), w_shapes)
+            y = fn(h.part, xp, h.weights)
+        return self._unpad_y(h, y)
 
     def _group_key(self, h: GraphHandle, x) -> tuple:
         if h.weights is None:
@@ -460,13 +473,11 @@ class Engine:
         tr = self.tracer
         if len(members) == 1:
             i, h, x, xp = members[0]
-            sp_pad = -1
-            if tr.enabled:
-                sp_pad = tr.begin("pad", "engine", args={"n": 1})
-            fn = ex.gcn(sc, f_in, w_shapes)
-            xpad = pad(h, x, xp)
-            part, weights = self._placed(h, device)
-            tr.end(sp_pad)
+            args = {"n": 1} if tr.enabled else None
+            with tr.span("pad", "engine", args):
+                fn = ex.gcn(sc, f_in, w_shapes)
+                xpad = pad(h, x, xp)
+                part, weights = self._placed(h, device)
             outs = [self._unpad_y(h, fn(part, xpad, weights))]
             meta = self._completion_meta(outs, misses0, ex)
             if inj.enabled:
@@ -480,34 +491,31 @@ class Engine:
         members.sort(key=lambda m: m[1].name)
         bs = 1 << (len(members) - 1).bit_length()
         padded = members + [members[-1]] * (bs - len(members))
-        sp_pad = -1
-        if tr.enabled:
-            sp_pad = tr.begin("pad", "engine",
-                              args={"n": len(members), "batch": bs})
-        fn = ex.gcn_batched(sc, f_in, w_shapes, bs)
-        # one stack per device: a lane's stack lives where it runs
-        stack_key = (device,) + tuple(h.name for _, h, _, _ in padded)
-        placed = [self._placed(h, device) for _, h, _, _ in padded]
-        with self._stack_lock:
-            stacks = self._stacks.get(stack_key)
-            if stacks is None:
-                self._stack_misses.inc()
-                part_stack = jtu.tree_map(
-                    lambda *leaves: jnp.stack(leaves),
-                    *[part for part, _ in placed])
-                w_stack = jtu.tree_map(
-                    lambda *ws: jnp.stack(ws),
-                    *[weights for _, weights in placed])
-                while len(self._stacks) >= self._max_stacks:
-                    self._stacks.popitem(last=False)       # LRU out
-                    self._stack_evictions.inc()
-                stacks = self._stacks[stack_key] = (part_stack, w_stack)
-            else:
-                self._stacks.move_to_end(stack_key)        # mark MRU
-                self._stack_hits.inc()
-        part_stack, w_stack = stacks
-        x_stack = jnp.stack([pad(h, x, xp) for _, h, x, xp in padded])
-        tr.end(sp_pad)
+        args = {"n": len(members), "batch": bs} if tr.enabled else None
+        with tr.span("pad", "engine", args):
+            fn = ex.gcn_batched(sc, f_in, w_shapes, bs)
+            # one stack per device: a lane's stack lives where it runs
+            stack_key = (device,) + tuple(h.name for _, h, _, _ in padded)
+            placed = [self._placed(h, device) for _, h, _, _ in padded]
+            with self._stack_lock:
+                stacks = self._stacks.get(stack_key)
+                if stacks is None:
+                    self._stack_misses.inc()
+                    part_stack = jtu.tree_map(
+                        lambda *leaves: jnp.stack(leaves),
+                        *[part for part, _ in placed])
+                    w_stack = jtu.tree_map(
+                        lambda *ws: jnp.stack(ws),
+                        *[weights for _, weights in placed])
+                    while len(self._stacks) >= self._max_stacks:
+                        self._stacks.popitem(last=False)       # LRU out
+                        self._stack_evictions.inc()
+                    stacks = self._stacks[stack_key] = (part_stack, w_stack)
+                else:
+                    self._stacks.move_to_end(stack_key)        # mark MRU
+                    self._stack_hits.inc()
+            part_stack, w_stack = stacks
+            x_stack = jnp.stack([pad(h, x, xp) for _, h, x, xp in padded])
         ys = fn(part_stack, x_stack, w_stack)
         results: list = [None] * len(members)
         for j, (i, h, _, _) in enumerate(members):
@@ -789,6 +797,7 @@ class Engine:
             "stack_max": self._max_stacks,
             "class_waste": self.class_waste(),
             "registry": self.registry.stats(),
+            "h2d_bytes": self._h2d_bytes.value,
             **stack,
         }
         if self._tuner is not None:

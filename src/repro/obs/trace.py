@@ -7,6 +7,8 @@ Design constraints, in order:
    (or nothing) before touching any other state. Instrumented call
    sites hold span ids as ints and guard with ``sid >= 0``, so a
    disabled tracer costs one attribute load + compare per site.
+   ``span`` adds one call that asks whether a profile is being taken,
+   and returns the shared `NULL_SPAN` when neither sink is on.
 2. **Lock-free when on.** The hot path takes no lock: slot indices and
    span ids come from ``itertools.count()`` (a single C-level ``next``,
    atomic under the GIL), and each event is one tuple stored into a
@@ -28,6 +30,14 @@ silently truncated "complete" trace.
 Sampling is deterministic: request ``seq`` is sampled iff
 ``seq % sample_every == 0``, so traced runs are reproducible under
 ``SimClock`` and the overhead gate compares identical schedules.
+
+``Tracer.span`` is the context-manager form for spans that begin and
+end on one thread. It has two sinks: the ring (while the tracer is
+enabled) and, while a ``jax.profiler`` trace is being taken, a
+``TraceAnnotation`` named ``"<cat>.<name>"``, with the span's args as
+its stats — which the profiler writes on the same clock as the device's
+ops. It works on
+``NULL_TRACER`` too, so a profile of any process shows the spans.
 """
 from __future__ import annotations
 
@@ -35,6 +45,12 @@ import itertools
 import threading
 import time
 from typing import Callable, List, Optional
+
+from jax._src.lib import _profiler
+from jax.profiler import TraceAnnotation
+
+# True while a jax.profiler trace is being taken (one C call).
+_profiling = _profiler.TraceMe.is_enabled
 
 # Tuple layout of one ring slot (kept a tuple, not a dataclass: one
 # allocation, immutable, wholesale-replaced on wrap).
@@ -106,6 +122,15 @@ class Tracer:
             i, "i", sid, parent, req, name, cat, self.clock(),
             threading.get_ident(), args)
 
+    def span(self, name: str, cat: str = "", args=None):
+        """Context manager for a span that begins and ends on this
+        thread: ring ``B``/``E`` events while enabled, a profiler
+        ``TraceAnnotation("<cat>.<name>", **args)`` while a profile is
+        taken, and the shared no-op `NULL_SPAN` when neither is on."""
+        if not (self.enabled or _profiling()):
+            return NULL_SPAN
+        return _Span(self, name, cat, args)
+
     def reject_id(self) -> int:
         """A synthetic (negative) request id for rejected submissions,
         which never receive a scheduler ``seq``."""
@@ -143,6 +168,46 @@ class Tracer:
         with self._lock:
             self._slots = [None] * self.capacity
             self._next = itertools.count()
+
+
+class _NullSpan:
+    """The span of a site with no sink on: does nothing."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc) -> None:
+        return None
+
+
+NULL_SPAN = _NullSpan()
+
+
+class _Span:
+    """One live `Tracer.span`; each sink is checked once, on entry."""
+
+    __slots__ = ("_tracer", "_name", "_cat", "_args", "_sid", "_ann")
+
+    def __init__(self, tracer: Tracer, name: str, cat: str, args):
+        self._tracer, self._name, self._cat = tracer, name, cat
+        self._args = args
+        self._sid, self._ann = -1, None
+
+    def __enter__(self):
+        if _profiling():
+            self._ann = TraceAnnotation(f"{self._cat}.{self._name}",
+                                        **(self._args or {}))
+            self._ann.__enter__()
+        self._sid = self._tracer.begin(self._name, self._cat,
+                                       args=self._args)
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self._tracer.end(self._sid)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
 
 
 def label(obj) -> str:
