@@ -12,6 +12,15 @@ dropped (and garbage-collects its XLA executable) when a new build would
 exceed the bound. Per-shape-class hit/miss/eviction counters feed
 ``Engine.stats()`` telemetry.
 
+Request staging rides in the same cache, in a table of its own with its
+own counters and the same bound: one jit'd function per (shape class,
+graph size, width) that permutes + zero-pads a request's features onto
+the class's input rows, and one that slices + un-permutes the output.
+Their inputs are per-graph shapes, so each graph size compiles its own
+pair; keeping them here bounds that growth, drops them with their class
+(``invalidate_class``), and counts their builds in ``builds``, the
+cold-detect delta the latency EWMAs read.
+
 The closed-over PartitionMeta comes from ``ShapeClass.to_meta()`` only,
 never from a member graph, so per-graph facts can't split a class.
 Padded partitions arrive as device arrays (Engine.register places them),
@@ -38,8 +47,8 @@ class CacheStats:
 
     One `Counter` per field — the unified metrics backing store — while
     the legacy integer attribute surface (``stats.hits`` etc.) survives
-    as read-only properties, so external readers (the frontend's
-    cold-detect delta on ``stats.misses``, tests, benchmark prints) are
+    as read-only properties, so external readers (tests, benchmark
+    prints) are
     unchanged. Mutation goes through the ``inc_*`` methods; multi-field
     coherence still comes from the owning ``ExecutorCache._lock`` — a
     counter's own lock only makes its single value race-free.
@@ -89,6 +98,27 @@ class CacheStats:
                 "invalidations": self.invalidations}
 
 
+def _stage_fn(rows: int):
+    def _stage_x(x, perm):
+        """Request features in the class's input layout: rows gathered
+        into the graph's reorder (``perm`` None: input order), then zero
+        rows up to ``rows``. Exact: no arithmetic touches a value."""
+        if perm is not None:
+            x = x[perm]
+        return jnp.pad(x, ((0, rows - x.shape[0]), (0, 0)))
+    return jax.jit(_stage_x)
+
+
+def _unstage_fn(n_rows: int):
+    def _unstage_y(y, inv_perm):
+        """Executor output back in the caller's vertex order: the
+        class's padding rows sliced off, then un-permuted (``inv_perm``
+        None: the graph was registered in input order)."""
+        y = y[:n_rows]
+        return y if inv_perm is None else y[inv_perm]
+    return jax.jit(_unstage_y)
+
+
 class ExecutorCache:
     """jit'd executors keyed by (kind, shape class, widths, backend...).
 
@@ -111,6 +141,11 @@ class ExecutorCache:
         self.metrics = MetricsRegistry()
         self.stats = CacheStats("cache", self.metrics)
         self._class_stats: dict = {}   # ShapeClass -> CacheStats
+        # Request staging functions (`stage` / `unstage`): a table and
+        # counters of their own, so they neither evict executors nor
+        # move the executor counters.
+        self._staging: collections.OrderedDict = collections.OrderedDict()
+        self.staging = CacheStats("cache.staging", self.metrics)
         # Observability hooks (repro.obs): cache.hit/cache.miss instant
         # events. Off by default; `Engine.attach_tracer` swaps it in.
         self.tracer = NULL_TRACER
@@ -171,6 +206,26 @@ class ExecutorCache:
                                args={"kind": key[0]})
             return fn
 
+    def _get_staging(self, key, build):
+        with self._lock:
+            fn = self._staging.get(key)
+            if fn is None:
+                self.staging.inc_misses()
+                fn = self._staging[key] = build()
+                while len(self._staging) > self.max_entries:
+                    self._staging.popitem(last=False)            # LRU out
+                    self.staging.inc_evictions()
+            else:
+                self._staging.move_to_end(key)                   # mark MRU
+                self.staging.inc_hits()
+            return fn
+
+    @property
+    def builds(self) -> int:
+        """Executors and staging functions this cache has built (traced
+        + compiled). A dispatch that raised it was cold."""
+        return self.stats.misses + self.staging.misses
+
     def __len__(self) -> int:
         with self._lock:
             return len(self._fns)
@@ -191,6 +246,11 @@ class ExecutorCache:
         with self._lock:
             return self.stats.as_dict()
 
+    def staging_snapshot(self) -> dict:
+        """Coherent copy of the staging counters and live entries."""
+        with self._lock:
+            return {**self.staging.as_dict(), "entries": len(self._staging)}
+
     def class_stats(self) -> dict:
         """Per-shape-class telemetry: {summary str: hit/miss/evict dict}."""
         with self._lock:
@@ -208,13 +268,14 @@ class ExecutorCache:
             return {sc: st.total for sc, st in self._class_stats.items()}
 
     def invalidate_class(self, sc: ShapeClass) -> int:
-        """Drop every cached executor keyed on ``sc`` (class retired).
+        """Drop every cached executor and staging function keyed on
+        ``sc`` (class retired).
 
         Distinct from LRU eviction — invalidations are counted
         separately (globally and per class) so capacity pressure and
         lifecycle churn stay distinguishable in telemetry. The LRU
         order of surviving entries is untouched. Returns the number of
-        executors dropped.
+        executors dropped; staging functions count in ``staging``.
         """
         with self._lock:
             dead = [key for key in self._fns if key[1] == sc]
@@ -223,6 +284,11 @@ class ExecutorCache:
             if dead:
                 self.stats.inc_invalidations(len(dead))
                 self._per_class(sc).inc_invalidations(len(dead))
+            staged = [key for key in self._staging if key[1] == sc]
+            for key in staged:
+                del self._staging[key]
+            if staged:
+                self.staging.inc_invalidations(len(staged))
             return len(dead)
 
     # -------------------------------------------------------- autotune -----
@@ -278,6 +344,26 @@ class ExecutorCache:
                                        ell_tune=ell_tune)
                 return fn
             return self._get(key, build)
+
+    # --------------------------------------------------------- staging -----
+    def stage(self, sc: ShapeClass, n_cols: int, f: int, permuted: bool):
+        """Staging of one graph size's request features onto class sc.
+
+        Signature: fn(x[n_cols, f], perm or None) ->
+        x[n_col_tiles * tile, f], compiled as module ``jit__stage_x``.
+        """
+        return self._get_staging(
+            ("stage", sc, n_cols, f, permuted),
+            lambda: _stage_fn(sc.n_col_tiles * sc.tile))
+
+    def unstage(self, sc: ShapeClass, n_rows: int, f: int, permuted: bool):
+        """Unstaging of class sc's output for a graph of ``n_rows``.
+
+        Signature: fn(y[n_row_tiles * tile, f], inv_perm or None) ->
+        y[n_rows, f], compiled as module ``jit__unstage_y``.
+        """
+        return self._get_staging(("unstage", sc, n_rows, f, permuted),
+                                 lambda: _unstage_fn(n_rows))
 
     # ------------------------------------------------------------- gcn -----
     def _gcn_key(self, sc, f_in, w_shapes):

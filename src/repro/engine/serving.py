@@ -4,8 +4,9 @@ Request path (mirrors the paper's offline/online split):
 
   offline  — ``register``: reorder, tri-partition (Algorithms 1+2), pad
              into a shape class. Done once per graph.
-  online   — ``spmm`` / ``infer``: pad the request features, run the
-             class's cached executor, slice + un-permute the output.
+  online   — ``spmm`` / ``infer``: send the request features to the
+             device, permute + pad them there, run the class's cached
+             executor, slice + un-permute the output on the device.
            — ``serve_batch``: group requests by (shape class, widths),
              then ``serve_group`` stacks each group and runs one
              vmapped executor per group.
@@ -22,8 +23,10 @@ unresolved device values — plus a completion meta dict (``cold`` flag,
 drainer uses to overlap the next batch's staging with this batch's
 device compute.
 
-All host-side padding/slicing happens outside jit, so the traced
-computation depends only on the shape class and feature widths.
+All padding/slicing happens outside the executors, so their traced
+computation depends only on the shape class and feature widths. The
+per-graph staging (permute, pad) and unstaging (slice, un-permute) are
+jitted device functions of their own, held in the same `ExecutorCache`.
 """
 from __future__ import annotations
 
@@ -31,7 +34,7 @@ import collections
 import dataclasses
 import threading
 import time
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import numpy as np
 import jax
@@ -60,20 +63,30 @@ class GraphHandle:
     meta: PartitionMeta         # original (true n_rows/n_cols/nnz)
     padded_meta: PartitionMeta  # the class's static meta + true nnz stats
     sclass: ShapeClass
-    perm: Optional[np.ndarray]  # vertex reorder permutation, or None
-    inv_perm: Optional[np.ndarray]
+    # vertex reorder permutation and its inverse on the device beside
+    # ``part``, or None for a graph registered in input order
+    perm: Optional[jax.Array]
+    inv_perm: Optional[jax.Array]
     weights: Optional[list]     # per-graph GCN weights (jnp), or None
     preprocess_s: float = 0.0
     # exact pre-snapping shape requirements, kept so the lifecycle can
     # re-classify this graph on retirement without re-partitioning
     need: Optional[ClassNeed] = None
-    # device -> (part, weights) copies for replica lanes bound to other
+    # device -> `_Placed` copies for replica lanes bound to other
     # devices, made on a lane's first dispatch (`Engine._placed`)
     copies: dict = dataclasses.field(default_factory=dict)
 
     @property
     def n_rows(self) -> int:
         return self.meta.n_rows
+
+
+class _Placed(NamedTuple):
+    """A graph's device arrays on one device."""
+    part: object
+    weights: Optional[list]
+    perm: Optional[jax.Array]
+    inv_perm: Optional[jax.Array]
 
 
 class _EngineReplicaView:
@@ -106,7 +119,8 @@ class _EngineReplicaView:
         return self._engine.latency_prior(key, batch)
 
     def prepare_x(self, name: str, x):
-        return self._engine.prepare_x(name, x, device=self.device)
+        return self._engine.prepare_x(name, x, device=self.device,
+                                      executors=self.executors)
 
     def serve_group_async(self, requests, prepared=None) -> tuple:
         return self._engine.serve_group_async(
@@ -158,8 +172,11 @@ class Engine:
         self._stack_misses = Counter("engine.stack_misses", self.metrics)
         self._stack_evictions = Counter("engine.stack_evictions",
                                         self.metrics)
-        # Bytes of padded request features `_pad_x` put on a device.
+        # Bytes of request features `_pad_x` put on a device.
         self._h2d_bytes = Counter("engine.h2d_bytes", self.metrics)
+        # Requests whose row permutation `_pad_x` ran on the device.
+        self._device_permutes = Counter("engine.device_permutes",
+                                        self.metrics)
         # Request tracer (repro.obs.trace): off by default; a serving
         # frontend constructed with `tracer=` calls `attach_tracer`,
         # which also fans the tracer out to the executor cache and the
@@ -225,15 +242,26 @@ class Engine:
         need = class_requirements(part, meta, self.policy)
         sc = self.registry.classify_need(need)
         padded, pmeta = pad_to_class(part, meta, sc)
-        # Place the padded partition on device once; jit args that are
-        # already device arrays are zero-copy on every later call.
-        padded = jax.device_put(padded)
+        # Place the padded partition and the permutation on device once;
+        # jit args that are already device arrays are zero-copy on every
+        # later call.
+        padded, perm, inv_perm = jax.device_put((padded, perm, inv_perm))
         handle = GraphHandle(
             name=name, part=padded, meta=meta, padded_meta=pmeta, sclass=sc,
             perm=perm, inv_perm=inv_perm,
             weights=None if weights is None else [jnp.asarray(w)
                                                   for w in weights],
             preprocess_s=time.perf_counter() - t0, need=need)
+        if handle.weights is not None:
+            # compile this graph's staging at its `infer` widths now, so
+            # its first request does not
+            f_in, classes = (handle.weights[0].shape[0],
+                             handle.weights[-1].shape[-1])
+            self._stage(self.executors, handle,
+                        jnp.zeros((meta.n_cols, f_in), jnp.float32), perm)
+            self._unstage(self.executors, handle,
+                          jnp.zeros((sc.n_row_tiles * sc.tile, classes),
+                                    jnp.float32), inv_perm)
         self._graphs[name] = handle
         # a re-registered name invalidates every cached group stack that
         # contains it — otherwise serve_batch would keep serving the old
@@ -272,48 +300,68 @@ class Engine:
                 self, i, cache, devices[i])
         return view
 
-    def _placed(self, h: GraphHandle, device) -> tuple:
-        """``h``'s (padded partition, weights) on ``device``; None means
-        where ``register`` put them. A device's copy is made once, on
-        its first dispatch."""
+    def _placed(self, h: GraphHandle, device) -> _Placed:
+        """``h``'s device arrays on ``device``; None means where
+        ``register`` put them. A device's copy is made once, on its
+        first dispatch."""
         if device is None:
-            return h.part, h.weights
+            return _Placed(h.part, h.weights, h.perm, h.inv_perm)
         with self._stack_lock:
             placed = h.copies.get(device)
             if placed is None:
                 placed = h.copies[device] = jax.device_put(
-                    (h.part, h.weights), device)
+                    _Placed(h.part, h.weights, h.perm, h.inv_perm), device)
         return placed
 
     # ---------------------------------------------------------- online -----
-    def _pad_x(self, h: GraphHandle, x, device=None) -> jnp.ndarray:
-        """Permute + zero-pad request features to the class input rows,
-        placed on ``device`` (None: the default device)."""
+    def _pad_x(self, h: GraphHandle, x, device=None,
+               ex: Optional[ExecutorCache] = None) -> jnp.ndarray:
+        """Request features on ``device`` (None: the default device),
+        permuted + zero-padded there to the class input rows by ``ex``'s
+        (None: the engine's) staging function."""
         x = np.asarray(x, np.float32)
         if x.shape[0] != h.meta.n_cols:
             raise ValueError(
                 f"request features have {x.shape[0]} rows; graph "
                 f"{h.name!r} expects {h.meta.n_cols}")
         tr = self.tracer
-        with tr.span("pad", "engine"):
-            if h.perm is not None:
-                x = x[h.perm]
-            want = h.sclass.n_col_tiles * h.sclass.tile
-            if x.shape[0] != want:
-                x = np.pad(x, ((0, want - x.shape[0]), (0, 0)))
         # PjRt starts its host transpose into the device layout here.
         with tr.span("h2d", "engine", {"bytes": x.nbytes}):
             xd = (jnp.asarray(x) if device is None
                   else jax.device_put(x, device))
         self._h2d_bytes.inc(x.nbytes)
+        perm = self._placed(h, device).perm
+        with tr.span("pad", "engine"):
+            xd = self._stage(self.executors if ex is None else ex, h, xd,
+                             perm)
+        if perm is not None:
+            self._device_permutes.inc()
         return xd
 
-    def _unpad_y(self, h: GraphHandle, y) -> jnp.ndarray:
+    def _unpad_y(self, h: GraphHandle, y, device=None,
+                 ex: Optional[ExecutorCache] = None) -> jnp.ndarray:
         with self.tracer.span("unpad", "engine"):
-            y = y[: h.n_rows]
-            if h.inv_perm is not None:
-                y = y[h.inv_perm]
-        return y
+            return self._unstage(self.executors if ex is None else ex, h, y,
+                                 self._placed(h, device).inv_perm)
+
+    @staticmethod
+    def _stage(ex: ExecutorCache, h: GraphHandle, xd, perm):
+        """``xd`` [n_cols, f] permuted + zero-padded on its device to the
+        rows of ``h``'s class, read at call time (a lifecycle
+        re-classification changes it)."""
+        sc = h.sclass
+        if perm is None and xd.shape[0] == sc.n_col_tiles * sc.tile:
+            return xd
+        return ex.stage(sc, *xd.shape, perm is not None)(xd, perm)
+
+    @staticmethod
+    def _unstage(ex: ExecutorCache, h: GraphHandle, y, inv_perm):
+        """Class-padded output ``y`` sliced to ``h``'s rows and
+        un-permuted, on its device."""
+        if inv_perm is None and y.shape[0] == h.n_rows:
+            return y
+        return ex.unstage(h.sclass, h.n_rows, y.shape[1],
+                          inv_perm is not None)(y, inv_perm)
 
     def spmm(self, name: str, b) -> jnp.ndarray:
         """Y = A @ B through the cached shape-class executor."""
@@ -401,14 +449,17 @@ class Engine:
         """
         return self.serve_group_async(requests)[0]
 
-    def prepare_x(self, name: str, x, device=None) -> jnp.ndarray:
-        """Stage one request's features: permute + pad to the graph's
-        class input rows and place on ``device`` (None: the default
-        device). Pure per-request work with no shared state, so
+    def prepare_x(self, name: str, x, device=None,
+                  executors: Optional[ExecutorCache] = None) -> jnp.ndarray:
+        """Stage one request's features: place them on ``device``
+        (None: the default device) and permute + pad them there to the
+        graph's class input rows, with ``executors``' (None: the
+        engine's) staging function. Per-request work that touches
+        shared state only under locks (`_placed`, the cache's), so
         pipelined staging workers may run it concurrently; the result
         feeds ``serve_group_async``'s ``prepared`` argument to move this
         cost off the ordered enqueue step."""
-        return self._pad_x(self._graphs[name], x, device)
+        return self._pad_x(self._graphs[name], x, device, executors)
 
     def serve_group_async(self, requests, prepared=None, *,
                           executors=None, device=None) -> tuple:
@@ -462,13 +513,13 @@ class Engine:
             xp = prepared[i] if prepared is not None else None
             members.append((i, h, x, xp))
         sc, f_in, w_shapes = key0
-        # Deliberate unguarded miss-counter read: a stale value only
+        # Deliberate unguarded builds-counter read: a stale value only
         # over-reports cold, which skips a warm sample and never poisons
         # the latency EWMA — see _completion_meta.
-        misses0 = ex.stats.misses  # lint: racy-ok(cold-detect delta; over-reports only)
+        builds0 = ex.builds  # lint: racy-ok(cold-detect delta; over-reports only)
 
         def pad(h, x, xp):
-            return xp if xp is not None else self._pad_x(h, x, device)
+            return xp if xp is not None else self._pad_x(h, x, device, ex)
 
         tr = self.tracer
         if len(members) == 1:
@@ -477,9 +528,10 @@ class Engine:
             with tr.span("pad", "engine", args):
                 fn = ex.gcn(sc, f_in, w_shapes)
                 xpad = pad(h, x, xp)
-                part, weights = self._placed(h, device)
-            outs = [self._unpad_y(h, fn(part, xpad, weights))]
-            meta = self._completion_meta(outs, misses0, ex)
+                placed = self._placed(h, device)
+            outs = [self._unpad_y(h, fn(placed.part, xpad, placed.weights),
+                                  device, ex)]
+            meta = self._completion_meta(outs, builds0, ex)
             if inj.enabled:
                 outs, meta = self._inject_async(inj, requests, outs, meta)
             return outs, meta
@@ -503,10 +555,10 @@ class Engine:
                     self._stack_misses.inc()
                     part_stack = jtu.tree_map(
                         lambda *leaves: jnp.stack(leaves),
-                        *[part for part, _ in placed])
+                        *[p.part for p in placed])
                     w_stack = jtu.tree_map(
                         lambda *ws: jnp.stack(ws),
-                        *[weights for _, weights in placed])
+                        *[p.weights for p in placed])
                     while len(self._stacks) >= self._max_stacks:
                         self._stacks.popitem(last=False)       # LRU out
                         self._stack_evictions.inc()
@@ -515,12 +567,15 @@ class Engine:
                     self._stacks.move_to_end(stack_key)        # mark MRU
                     self._stack_hits.inc()
             part_stack, w_stack = stacks
-            x_stack = jnp.stack([pad(h, x, xp) for _, h, x, xp in padded])
+            # each member is staged once; the batch's filler slots
+            # repeat the last member's staged features
+            xs = [pad(h, x, xp) for _, h, x, xp in members]
+            x_stack = jnp.stack(xs + [xs[-1]] * (bs - len(members)))
         ys = fn(part_stack, x_stack, w_stack)
         results: list = [None] * len(members)
         for j, (i, h, _, _) in enumerate(members):
-            results[i] = self._unpad_y(h, ys[j])
-        meta = self._completion_meta(results, misses0, ex)
+            results[i] = self._unpad_y(h, ys[j], device, ex)
+        meta = self._completion_meta(results, builds0, ex)
         if inj.enabled:
             results, meta = self._inject_async(inj, requests, results, meta)
         return results, meta
@@ -544,10 +599,10 @@ class Engine:
             meta["complete"] = hung_complete
         return outs, meta
 
-    def _completion_meta(self, outs, misses0: int, ex=None) -> dict:
+    def _completion_meta(self, outs, builds0: int, ex=None) -> dict:
         """The async-dispatch completion contract for one enqueued group.
 
-        ``cold`` is a miss-counter delta on the cache that served the
+        ``cold`` is a ``builds`` delta on the cache that served the
         dispatch (a replica view's own, or the engine's): under
         concurrent staging a sibling's miss can be misattributed, which
         only *over*-reports cold — a skipped warm sample, never a
@@ -565,7 +620,7 @@ class Engine:
                 if blocker is not None:
                     blocker()
 
-        return {"cold": ex.stats.misses > misses0,  # lint: racy-ok(cold-detect delta; over-reports only)
+        return {"cold": ex.builds > builds0,  # lint: racy-ok(cold-detect delta; over-reports only)
                 "ready": ready, "complete": complete}
 
     # --------------------------------------------------------- latency -----
@@ -794,10 +849,12 @@ class Engine:
             "cache_misses": cache["misses"],
             "cache_evictions": cache["evictions"],
             "per_class": self.executors.class_stats(),
+            "staging": self.executors.staging_snapshot(),
             "stack_max": self._max_stacks,
             "class_waste": self.class_waste(),
             "registry": self.registry.stats(),
             "h2d_bytes": self._h2d_bytes.value,
+            "device_permutes": self._device_permutes.value,
             **stack,
         }
         if self._tuner is not None:

@@ -28,7 +28,7 @@ Cold samples (a dispatch that triggered an executor compile) must NOT be
 folded into ANY segment: jit compiles run synchronously inside the first
 call, so a cold sample inflates the *staging* segment by orders of
 magnitude, and the XLA-side warmup pollutes the device segment too. The
-queue detects compiles via the executor cache's miss counter (serial
+queue detects compiles via the executor cache's ``builds`` (serial
 path) or the ``cold`` flag in ``serve_group_async``'s completion meta
 (pipelined path) and reports them with ``cold=True``; they are counted
 but never averaged — per segment and per total alike.

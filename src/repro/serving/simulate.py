@@ -5,7 +5,7 @@ push without paying a single XLA compile or depending on wall-clock
 timing. This module fakes the only two things the frontend touches —
 the clock (`SimClock`) and the engine (`StubEngine`, a configurable
 service-time model with the same ``handle`` / ``serve_group`` /
-``executors.stats.misses`` surface) — so an entire arrival trace replays
+``executors.builds`` surface) — so an entire arrival trace replays
 in microseconds, bit-for-bit reproducibly.
 
 Multi-replica simulation: ``StubEngine(..., replicas=N)`` models N
@@ -96,6 +96,12 @@ class _StubExecutors:
     def __init__(self):
         self.stats = _StubExecStats()
 
+    @property
+    def builds(self) -> int:
+        """The frontend's cold-detect count (the stub builds only
+        executors)."""
+        return self.stats.misses
+
 
 @dataclasses.dataclass(frozen=True)
 class StubShapeClass:
@@ -166,7 +172,7 @@ class StubEngine:
 
     ``service_s(batch)`` models warm dispatch latency; the first dispatch
     of each (group key, padded batch) additionally pays ``compile_s`` and
-    bumps the executor-cache miss counter — exactly the signal the
+    bumps the executor-cache build count — exactly the signal the
     frontend uses to keep cold samples out of the EWMA.
 
     Lifecycle surface: registering with a ``size`` switches the stub

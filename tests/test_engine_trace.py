@@ -17,7 +17,7 @@ from repro.obs.trace import NULL_SPAN, NULL_TRACER, Tracer
 
 from conftest import make_heterogeneous_matrix
 
-ENGINE_SPANS = ("engine.pad", "engine.h2d", "engine.launch",
+ENGINE_SPANS = ("engine.h2d", "engine.pad", "engine.launch",
                 "engine.unpad")
 SCOPES = ("combine", "agg.dense", "agg.ell", "agg.coo")
 F_IN, HIDDEN, CLASSES = 16, 8, 4
@@ -113,7 +113,7 @@ def test_infer_spans_are_in_the_profile_on_the_calling_thread(
     assert all(lo <= s <= e <= hi for _, s, e in mine)
     if with_tracer:
         names = [e["name"] for e in tracer.events() if e["ph"] == "B"]
-        assert names == ["pad", "h2d", "launch", "unpad"]
+        assert names == ["h2d", "pad", "launch", "unpad"]
 
 
 def test_h2d_span_carries_the_bytes_the_counter_adds(tmp_path, engine):
@@ -165,12 +165,15 @@ def test_executor_hlo_carries_the_engine_scopes(engine, batch):
 
 # ---------------------------------------------------------- counter ----
 def test_h2d_bytes_counts_the_padded_features_of_each_infer(engine):
+    """The features cross to the device unpadded, in input order: the
+    class's zero rows are added on the device (`_stage_x`), so the
+    counter adds exactly the request's own rows."""
     h = engine.handle("g")
-    rows = h.sclass.n_col_tiles * h.sclass.tile
+    assert h.sclass.n_col_tiles * h.sclass.tile > h.meta.n_cols
     before = engine.stats()["h2d_bytes"]
     for seed in range(3):
         engine.infer("g", _x(seed))
     grown = engine.stats()["h2d_bytes"] - before
-    assert grown == 3 * rows * F_IN * 4
+    assert grown == 3 * h.meta.n_cols * F_IN * 4
     assert engine.metrics.get("engine.h2d_bytes").value == \
         engine.stats()["h2d_bytes"]

@@ -153,3 +153,22 @@ def test_batched_pallas_gcn_executor_compiles(graph, served, one_chip,
     weights = [_on(one_chip, w, (batch,)) for w in h.weights]
     # per layer: one dense-tile launch and ONE ragged ELL launch
     assert _custom_calls(fn, part, x, weights) == 2 * len(h.weights)
+
+
+@pytest.mark.parametrize("permuted", [True, False], ids=["perm", "no_perm"])
+@pytest.mark.parametrize("graph", GRAPHS)
+def test_request_staging_compiles(graph, permuted, served, one_chip):
+    """The device-side permute + zero-pad of a request's features to the
+    class's rows, and the slice + un-permute of its logits."""
+    from repro.engine.executor import _stage_fn, _unstage_fn
+    _, h, f_in = served[graph]
+    n, rows = h.meta.n_cols, h.sclass.n_col_tiles * h.sclass.tile
+    perm = (jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip)
+            if permuted else None)
+    x = jax.ShapeDtypeStruct((n, f_in), jnp.float32, sharding=one_chip)
+    staged = _stage_fn(rows).lower(x, perm).compile().as_text()
+    assert f"f32[{rows},{f_in}]" in staged.split("ENTRY", 1)[1]
+    y = jax.ShapeDtypeStruct((h.padded_meta.n_padded_rows, N_CLASSES),
+                             jnp.float32, sharding=one_chip)
+    unstaged = _unstage_fn(h.n_rows).lower(y, perm).compile().as_text()
+    assert f"f32[{h.n_rows},{N_CLASSES}]" in unstaged.split("ENTRY", 1)[1]
