@@ -2,15 +2,16 @@
 
     python3 bench/control.py --workload <cell> --seeds 1,2,3
 
-For each seed: the cell's graphs, weights and feature pool as a run
-makes them, registered with a fresh `Engine` as a run registers them.
-Each compared number (`bench.lib.harness.COMPARED`) is read, widest
-over the pool, for the program's ``Engine.infer`` and for the two
-controls put in its place: the reference with its matmul operands in
-float8 (``fp8``) and the reference computed wholly in bfloat16
-(``bf16``). One JSON line per seed, then one with the program's largest
-and each control's smallest reading of each number: a limit lies above
-the first and below the second.
+For each seed: the cell's graphs, parameters and feature pool as a run
+makes them, registered with a fresh `Engine` as a run registers them
+(all through the configuration's model file). Each compared number
+(`bench.lib.harness.COMPARED`) is read, widest over the pool, for the
+program's ``Engine.infer`` and for the two controls put in its place:
+the model's plain forward with its matmul operands in float8 (``fp8``)
+and computed wholly in bfloat16 (``bf16``). One JSON line per seed,
+then one with the program's largest and each control's smallest
+reading of each number: a limit lies above the first and below the
+second.
 """
 from __future__ import annotations
 
@@ -39,26 +40,25 @@ def main(argv=None) -> int:
     import jax
     import numpy as np
     from repro.engine import Engine
-    graphs = harness.build_graphs(cell.config)
+    model = cell.model
+    graphs = harness.build_graphs(cell.config, model)
     sides = {"fp8": ref_lib.reference_fp8, "bf16": ref_lib.reference_bf16}
     extreme = {}
     for seed in (int(s) for s in args.seeds.split(",")):
-        harness.make_weights(seed, graphs)
+        harness.make_params(seed, cell.config, model, graphs)
         harness.make_pools(seed, cell.config, cell.traffic, graphs)
         engine = Engine()
         for g in graphs:
-            engine.register(g.name, harness._program_csr(g.csr),
-                            reorder=cell.config["reorder"],
-                            weights=g.weights)
+            model.register(engine, g.name, harness._program_csr(g.csr),
+                           reorder=cell.config["reorder"], params=g.params)
         worst = {side: dict.fromkeys(harness.COMPARED, 0.0)
                  for side in ("program", *sides)}
         for g in graphs:
             for x in g.pool:
                 ys = {"program": np.asarray(engine.infer(g.name, x))}
-                ys.update((side, fn(g.csr, x, g.weights))
+                ys.update((side, fn(model.forward, g.csr, x, g.params))
                           for side, fn in sides.items())
-                refs = (ref_lib.reference(g.csr, x, g.weights),
-                        ref_lib.reference_stated(g.csr, x, g.weights))
+                refs = harness.references(model, g, x)
                 for side, y in ys.items():
                     for k, v in harness.numbers(y, *refs).items():
                         worst[side][k] = max(worst[side][k], v)

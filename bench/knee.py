@@ -37,11 +37,12 @@ def main(argv=None) -> int:
     from bench.lib import harness
     from repro.serving.frontend import DEFAULT_DEADLINE_MS
     name = f"{args.config}/{args.traffic}"
+    config = harness.load_json(harness.BENCH_DIR, "configs", args.config)
     cell = harness.Cell(
-        name=name, chips=1, metrics=[],
-        config=harness.load_json(harness.BENCH_DIR, "configs", args.config),
+        name=name, chips=1, metrics=[], config=config,
         traffic=harness.load_json(harness.BENCH_DIR, "traffic",
-                                  args.traffic))
+                                  args.traffic),
+        model=harness.load_model(harness.BENCH_DIR, config))
     err = harness.chip_error(cell.chips)
     if err is not None:
         raise SystemExit(f"{name}: {err}")

@@ -2,24 +2,28 @@
 
 A cell names a configuration (``configs/<name>.json``: the model and
 the graphs it serves) and a traffic mix (``traffic/<name>.json``, read
-by `bench.lib.traffic`); each metric it reports is a reader
-``metrics/<name>.py`` whose ``read(run)`` returns a number or None.
-Nothing here names a configuration, a traffic mix or a metric.
+by `bench.lib.traffic`). The configuration's ``model.kind`` names a
+model file ``models/<kind>.py``: its parameters, operation count, plain
+forward and registration with the program. Each metric a cell reports
+is a reader ``metrics/<name>.py`` whose ``read(run)`` returns a number
+or None. Nothing here names a configuration, a model, a traffic mix or
+a metric.
 
 One run:
 
   set-up   synthesize the graphs (structure fixed by the configuration),
-           make weights on the device and the feature pool from the
-           seed, register every graph with the `Engine` (its default
-           backend), build the served entry, compile or load from the
-           persistent cache every shape the traffic uses, and send
-           ``warm_requests`` through it;
+           make the model's parameters on the device and the feature
+           pool from the seed, register every graph with the `Engine`
+           (its default backend) as the model file does, build the
+           served entry, compile or load from the persistent cache
+           every shape the traffic uses, and send ``warm_requests``
+           through it;
   window   drive the traffic for ``seconds``; with ``trace`` a
            ``jax.profiler`` trace covers exactly the window;
   check    wait for the window's answers, read the peak device memory,
-           free the program, then compare every answer with the plain
-           float32 reference and the reference at the configuration's
-           stated precision (`bench.lib.reference`).
+           free the program, then compare every answer with the model's
+           plain forward in float32 and at the configuration's stated
+           precision (`bench.lib.reference`).
 """
 from __future__ import annotations
 
@@ -32,6 +36,7 @@ import shutil
 import threading
 import time
 from pathlib import Path
+from types import ModuleType
 from typing import Callable, List, Optional
 
 import numpy as np
@@ -40,7 +45,6 @@ from . import graphs as graph_lib
 from . import reference as ref_lib
 from . import trace as trace_lib
 from . import traffic as traffic_lib
-from .flops import gcn_flops
 
 BENCH_DIR = Path(__file__).resolve().parents[1]
 CHECKOUT = BENCH_DIR.parent
@@ -81,6 +85,7 @@ class Cell:
     traffic: dict
     chips: int
     metrics: List[dict]        # BENCHMARK.json entries this run reports
+    model: ModuleType          # models/<config.model.kind>.py
 
 
 def load_json(bench_dir: Path, kind: str, name: str) -> dict:
@@ -88,14 +93,33 @@ def load_json(bench_dir: Path, kind: str, name: str) -> dict:
         return json.load(f)
 
 
-def load_reader(bench_dir: Path, name: str) -> Callable:
-    """``read`` of ``metrics/<name>.py``."""
-    path = Path(bench_dir) / "metrics" / f"{name}.py"
+def _load_module(path: Path, prefix: str) -> ModuleType:
     spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name.replace('.', '_').replace('-', '_')}", path)
+        prefix + path.stem.replace(".", "_").replace("-", "_"), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_reader(bench_dir: Path, name: str) -> Callable:
+    """``read`` of ``metrics/<name>.py``."""
+    return _load_module(Path(bench_dir) / "metrics" / f"{name}.py",
+                        "bench_metric_").read
+
+
+def load_model(bench_dir: Path, config: dict) -> ModuleType:
+    """``models/<kind>.py`` of the configuration's ``model.kind``:
+    ``make_params(key, model, spec)``, ``flops(csr, model, spec)``,
+    ``forward(rows, cols, vals, x, params, *, n, dtype, precision,
+    operands)`` and ``register(engine, name, csr, *, reorder,
+    params)``."""
+    kind = config["model"]["kind"]
+    path = Path(bench_dir) / "models" / f"{kind}.py"
+    if not path.is_file():
+        raise FileNotFoundError(
+            f"configuration {config.get('name')!r} names model kind "
+            f"{kind!r}, and there is no {path}")
+    return _load_module(path, "bench_model_")
 
 
 def load_cell(benchmark: dict, workload: str, trace: bool,
@@ -119,59 +143,50 @@ def load_cell(benchmark: dict, workload: str, trace: bool,
                    if applies(m) and m["moves"] in moved]
     else:
         metrics = e2e
-    return Cell(name=workload,
-                config=load_json(bench_dir, "configs",
-                                 Path(cfg["file"]).stem),
+    config = load_json(bench_dir, "configs", Path(cfg["file"]).stem)
+    return Cell(name=workload, config=config,
                 traffic=load_json(bench_dir, "traffic", w["traffic"]),
-                chips=int(w["chips"]), metrics=metrics)
+                chips=int(w["chips"]), metrics=metrics,
+                model=load_model(bench_dir, config))
 
 
 # --------------------------------------------------------------- set-up ----
 @dataclasses.dataclass
 class Graph:
     name: str
+    spec: dict                  # the configuration's entry of this graph
     csr: object                 # scipy CSR of A_tilde
-    widths: list                # [f_in, hidden..., classes]
     flops: float                # per request
-    weights: list = None        # device arrays
+    params: object = None       # the model's parameters, on the device
     pool: list = None           # host feature matrices
 
 
-def build_graphs(config: dict) -> List[Graph]:
-    model = config["model"]
+def build_graphs(config: dict, model: ModuleType) -> List[Graph]:
     out = []
     for spec in config["graphs"]:
         csr = graph_lib.make_graph(spec, config["graph_seed"])
-        widths = ([spec["n_features"]]
-                  + [model["d_hidden"]] * (model["n_layers"] - 1)
-                  + [spec["n_classes"]])
-        out.append(Graph(spec["name"], csr, widths,
-                         gcn_flops(csr.shape[0], csr.nnz, widths)))
+        out.append(Graph(spec["name"], spec, csr,
+                         model.flops(csr, config["model"], spec)))
     return out
 
 
-def make_weights(seed: int, graphs: List[Graph]) -> None:
-    """Glorot-normal float32 weights of every graph, made on the device
-    in one jitted call from the seed."""
+def make_params(seed: int, config: dict, model: ModuleType,
+                graphs: List[Graph]) -> None:
+    """The parameters of every graph, made on the device in one jitted
+    call from the seed: graph ``i``'s by the model file from
+    ``fold_in(root, i)``."""
     import jax
-    import jax.numpy as jnp
-    dims = tuple(tuple(g.widths) for g in graphs)
+    specs = [g.spec for g in graphs]
 
     def gen(key):
-        out = []
-        for gi, widths in enumerate(dims):
-            kg = jax.random.fold_in(key, gi)
-            out.append([jax.random.normal(jax.random.fold_in(kg, li), (a, b),
-                                          jnp.float32)
-                        * np.float32(np.sqrt(2.0 / (a + b)))
-                        for li, (a, b) in enumerate(zip(widths[:-1],
-                                                        widths[1:]))])
-        return out
+        return [model.make_params(jax.random.fold_in(key, gi),
+                                  config["model"], spec)
+                for gi, spec in enumerate(specs)]
 
     key = jax.random.fold_in(jax.random.key(seed & 0xFFFFFFFF),
                              (seed >> 32) & 0xFFFFFFFF)
-    for g, ws in zip(graphs, jax.jit(gen)(key)):
-        g.weights = ws
+    for g, params in zip(graphs, jax.jit(gen)(key)):
+        g.params = params
 
 
 def make_pools(seed: int, config: dict, traffic: dict,
@@ -182,7 +197,7 @@ def make_pools(seed: int, config: dict, traffic: dict,
     for gi, g in enumerate(graphs):
         rng = traffic_lib.rng_for(seed, 100 + gi)
         n = g.csr.shape[0]
-        x = graph_lib.make_features(n, g.widths[0], density, rng)
+        x = graph_lib.make_features(n, g.spec["n_features"], density, rng)
         g.pool = [x * (rng.random(n) < traffic["keep"]).astype(
             np.float32)[:, None] for _ in range(traffic["pool"])]
 
@@ -458,10 +473,12 @@ class Run:
 COMPARED = ("max_err_ratio", "bf16_exact_share")
 
 
-def compare(records: List[Rec], graphs: List[Graph]) -> dict:
-    """Every answer against the references of its pool entry
-    (`bench.lib.reference`): sets each answer's ``err``, each number of
-    `COMPARED`, and returns the largest of each over the answers."""
+def compare(records: List[Rec], graphs: List[Graph],
+            model: ModuleType) -> dict:
+    """Every answer against the references of its pool entry (the
+    model's forward, `bench.lib.reference`): sets each answer's
+    ``err``, each number of `COMPARED`, and returns the largest of each
+    over the answers."""
     worst = dict.fromkeys(COMPARED, 0.0)
     refs: dict = {}
     for rec in records:
@@ -470,13 +487,18 @@ def compare(records: List[Rec], graphs: List[Graph]) -> dict:
         key = (rec.graph, rec.pool_idx)
         if key not in refs:
             g = graphs[rec.graph]
-            x = g.pool[rec.pool_idx]
-            refs[key] = (ref_lib.reference(g.csr, x, g.weights),
-                         ref_lib.reference_stated(g.csr, x, g.weights))
+            refs[key] = references(model, g, g.pool[rec.pool_idx])
         rec.err = numbers(rec.y, *refs[key])
         for k, v in rec.err.items():
             worst[k] = max(worst[k], v)
     return worst
+
+
+def references(model: ModuleType, g: Graph, x) -> tuple:
+    """The float32 and the stated-precision references of features
+    ``x`` of graph ``g``."""
+    return (ref_lib.reference(model.forward, g.csr, x, g.params),
+            ref_lib.reference_stated(model.forward, g.csr, x, g.params))
 
 
 def numbers(y, ref, stated) -> dict:
@@ -491,7 +513,7 @@ def set_up(cell: Cell, seed: int, trace: bool, log=print) -> tuple:
     entry's engine has every graph registered, every shape the traffic
     uses compiled or loaded, and has answered ``warm_requests``."""
     from repro.engine import Engine
-    cfg, trf = cell.config, cell.traffic
+    cfg, trf, model = cell.config, cell.traffic, cell.model
     t = clock()
 
     def phase(name):
@@ -500,15 +522,15 @@ def set_up(cell: Cell, seed: int, trace: bool, log=print) -> tuple:
         log(f"set-up: {name} {now - t:.3f} s")
         t = now
 
-    graphs = build_graphs(cfg)
+    graphs = build_graphs(cfg, model)
     phase("graphs")
-    make_weights(seed, graphs)
+    make_params(seed, cfg, model, graphs)
     make_pools(seed, cfg, trf, graphs)
-    phase("weights and features")
+    phase("parameters and features")
     engine = Engine()
     for g in graphs:
-        h = engine.register(g.name, _program_csr(g.csr),
-                            reorder=cfg["reorder"], weights=g.weights)
+        h = model.register(engine, g.name, _program_csr(g.csr),
+                           reorder=cfg["reorder"], params=g.params)
         phase(f"register {g.name} ({h.sclass.summary()})")
     entry = ENTRIES[trf["entry"]](engine, trf, trace)
     entry.warm_shapes(graphs)
@@ -559,7 +581,7 @@ def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool,
 
     summary = trace_lib.reduce(trace_lib.load(str(TRACE_DIR))) \
         if trace else None
-    worst = compare(records, graphs)
+    worst = compare(records, graphs, cell.model)
     limits = cfg["limits"]
     unanswered = [r for r in records if r.y is None and not r.refused]
     ok = [r.y is not None and all(r.err[k] <= limits[k] for k in COMPARED)

@@ -1,16 +1,24 @@
-"""The plain GCN forward that decides ``correct``, and its controls.
+"""The plain references that decide ``correct``, and their controls.
 
-``reference`` is the paper's 2-layer GCN, ``A_tilde relu(A_tilde X W1)
-W2``, written with nothing but ``jax.numpy``: ``X W`` at HIGHEST
-precision and the aggregation as a ``segment_sum`` over the CSR's
-(row, col, value) triples, all in float32. It knows nothing of the
-program's partition, padding, reordering or batching.
+Each configuration's model file (``models/<kind>.py``) has a
+``forward(rows, cols, vals, x, params, *, n, dtype, precision,
+operands)``: the model written with nothing but ``jax.numpy`` over the
+CSR's (row, col, value) triples. It knows nothing of the program's
+partition, padding, reordering or batching. This module runs it at four
+precisions, the same for every model:
 
-The configurations state float32 storage and the TPU's default matmul
-precision, which rounds both operands of every matmul to bfloat16 (one
-pass, float32 accumulation). ``reference_stated`` is the same forward
-at that stated precision: the operands of ``X W`` rounded to bfloat16,
-their products and sums and the whole aggregation in float32.
+``reference``         float32 throughout, matmuls at HIGHEST;
+``reference_stated``  the configurations' stated precision: float32
+                      storage and the TPU's default matmul precision,
+                      which rounds both operands of every matmul to
+                      bfloat16 (one pass, float32 accumulation); the
+                      operands are rounded to bfloat16, their products
+                      and sums and everything else stay in float32;
+``reference_fp8``     a control: matmul operands rounded to float8
+                      e4m3, one step below the stated bfloat16;
+``reference_bf16``    a control: every array and intermediate in
+                      bfloat16, one step below the stated float32
+                      storage.
 
 Two numbers are compared for each answer:
 
@@ -20,13 +28,9 @@ Two numbers are compared for each answer:
                       for a program that rounds as the configuration
                       states, on every seed, where the widest gap
                       alone swings with the weights' scale. Its
-                      control, ``reference_fp8``, rounds the matmul
-                      operands to float8 (e4m3), one step below the
-                      stated bfloat16 operands.
+                      control is ``reference_fp8``.
 ``bf16_exact_share``  the share of the answer's logits that a bfloat16
                       holds exactly. Its control, ``reference_bf16``,
-                      keeps every array and intermediate in bfloat16,
-                      one step below the stated float32 storage, and
                       reads 1; a float32 answer reads the share of its
                       exact zeros. No gap to a reference separates
                       this control: the program's own matmuls round
@@ -36,11 +40,11 @@ Two numbers are compared for each answer:
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _triples(a) -> tuple:
@@ -50,56 +54,36 @@ def _triples(a) -> tuple:
             jnp.asarray(a.data.astype(np.float32)))
 
 
-@functools.partial(jax.jit, static_argnames=("n", "dtype", "precision",
-                                             "operands"))
-def _forward(rows, cols, vals, x, weights, *, n, dtype, precision,
-             operands=None):
-    vals = vals.astype(dtype)
-    h = x.astype(dtype)
-
-    def rounded(a):
-        return a if operands is None else a.astype(operands).astype(dtype)
-
-    for i, w in enumerate(weights):
-        hw = jnp.dot(rounded(h), rounded(w.astype(dtype)),
-                     precision=precision)
-        h = jax.ops.segment_sum(vals[:, None] * hw[cols], rows,
-                                num_segments=n)
-        if i < len(weights) - 1:
-            h = jax.nn.relu(h)
-    return h.astype(jnp.float32)
+def _run(forward, a, x, params, *, dtype, precision,
+         operands=None) -> np.ndarray:
+    return np.asarray(forward(*_triples(a), jnp.asarray(x), params,
+                              n=a.shape[0], dtype=dtype, precision=precision,
+                              operands=operands))
 
 
-def reference(a, x, weights) -> np.ndarray:
-    """float32 logits [n, classes] of the GCN over CSR ``a`` (scipy),
-    features ``x`` [n, f] and ``weights`` [[f, h], [h, c]]."""
-    return np.asarray(_forward(*_triples(a), jnp.asarray(x), list(weights),
-                               n=a.shape[0], dtype=jnp.float32,
-                               precision=jax.lax.Precision.HIGHEST))
+def reference(forward, a, x, params) -> np.ndarray:
+    """float32 logits of the model ``forward`` over CSR ``a`` (scipy),
+    features ``x`` [n, f] and parameters ``params``."""
+    return _run(forward, a, x, params, dtype=jnp.float32, precision=HIGHEST)
 
 
-def reference_stated(a, x, weights) -> np.ndarray:
-    """The forward at the stated precision: ``X W`` operands rounded
+def reference_stated(forward, a, x, params) -> np.ndarray:
+    """The forward at the stated precision: matmul operands rounded
     to bfloat16, float32 everywhere else."""
-    return np.asarray(_forward(*_triples(a), jnp.asarray(x), list(weights),
-                               n=a.shape[0], dtype=jnp.float32,
-                               precision=jax.lax.Precision.HIGHEST,
-                               operands=jnp.bfloat16))
+    return _run(forward, a, x, params, dtype=jnp.float32, precision=HIGHEST,
+                operands=jnp.bfloat16)
 
 
-def reference_bf16(a, x, weights) -> np.ndarray:
+def reference_bf16(forward, a, x, params) -> np.ndarray:
     """The control: the same forward computed in bfloat16."""
-    return np.asarray(_forward(*_triples(a), jnp.asarray(x), list(weights),
-                               n=a.shape[0], dtype=jnp.bfloat16,
-                               precision=jax.lax.Precision.DEFAULT))
+    return _run(forward, a, x, params, dtype=jnp.bfloat16,
+                precision=jax.lax.Precision.DEFAULT)
 
 
-def reference_fp8(a, x, weights) -> np.ndarray:
+def reference_fp8(forward, a, x, params) -> np.ndarray:
     """The control: matmul operands rounded to float8 e4m3."""
-    return np.asarray(_forward(*_triples(a), jnp.asarray(x), list(weights),
-                               n=a.shape[0], dtype=jnp.float32,
-                               precision=jax.lax.Precision.HIGHEST,
-                               operands=jnp.float8_e4m3fn))
+    return _run(forward, a, x, params, dtype=jnp.float32, precision=HIGHEST,
+                operands=jnp.float8_e4m3fn)
 
 
 def max_err_ratio(y, ref: np.ndarray, stated: np.ndarray) -> float:

@@ -1,6 +1,6 @@
-"""Device time of the GCN executables (XLA modules ``jit_fwd``, the
-program's `ExecutorCache` forward, single and batched) in the traced
-window, per request answered in it."""
+"""Device time of the model's forward executables (XLA modules
+``jit_fwd``, the program's `ExecutorCache` forward, single and
+batched) in the traced window, per request answered in it."""
 
 MODULE = "jit_fwd"
 

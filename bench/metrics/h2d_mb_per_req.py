@@ -1,7 +1,7 @@
-"""Megabytes (1e6 bytes) of padded request features the program put on
-the device in the traced run (the ``bytes`` of its ``engine.h2d`` spans,
-what its counter ``engine.h2d_bytes`` adds), per request the window
-dispatched (`bench.lib.marks`)."""
+"""Megabytes (1e6 bytes) of unpadded ``[n_cols, f]`` request features
+the program put on the device in the traced run (the ``bytes`` of its
+``engine.h2d`` spans, what its counter ``engine.h2d_bytes`` adds), per
+request the window dispatched (`bench.lib.marks`)."""
 from bench.lib import marks
 
 

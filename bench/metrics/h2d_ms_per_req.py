@@ -1,6 +1,7 @@
 """Host time of the program's ``engine.h2d`` spans (``jnp.asarray`` /
-``device_put`` of the padded features, on the calling thread) in the
-traced window, per request answered in it (`bench.lib.marks`)."""
+``device_put`` of the unpadded, unpermuted ``[n_cols, f]`` request
+features, on the calling thread) in the traced window, per request
+answered in it (`bench.lib.marks`)."""
 from bench.lib import marks
 
 

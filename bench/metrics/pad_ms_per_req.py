@@ -1,6 +1,8 @@
-"""Host time of the program's ``engine.pad`` spans (permute and zero-pad
-of the request features on the host) in the traced window, per request
-answered in it (`bench.lib.marks`)."""
+"""Host time of the program's ``engine.pad`` spans (the cache lookup
+and host dispatch of the device permute and zero-pad of the request
+features, ``jit__stage_x``, whose device time `stage_device_ms_per_req`
+reads) in the traced window, per request answered in it
+(`bench.lib.marks`)."""
 from bench.lib import marks
 
 

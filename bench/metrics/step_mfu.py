@@ -1,6 +1,7 @@
 """The whole step's share of the chip's peak, in %: model FLOPs of the
-requests answered in the traced window (unpadded shapes,
-`bench.lib.flops`) over window seconds x peak bf16 FLOP/s x chips."""
+requests answered in the traced window (unpadded shapes, ``flops`` of
+the configuration's model file ``models/<kind>.py``) over window
+seconds x peak bf16 FLOP/s x chips."""
 
 
 def read(run):

@@ -1,11 +1,13 @@
 """CPU tests of the benchmark harness: trace reduction, operation
-counts, traffic, lookup by name, whole runs at a tiny size, and runs
-with the served path broken underneath, which must come out incorrect.
+counts, traffic, lookup by name (of models too), whole runs at a tiny
+size, and runs with the served path broken underneath, which must come
+out incorrect.
 
 Run with ``JAX_PLATFORMS=cpu python -m pytest bench/tests``."""
 from __future__ import annotations
 
 import collections
+import hashlib
 import json
 import os
 import subprocess
@@ -15,7 +17,7 @@ from pathlib import Path
 import numpy as np
 import pytest
 
-from bench.lib import flops, harness, peaks, reference, trace, traffic
+from bench.lib import harness, peaks, reference, trace, traffic
 from bench.lib import graphs as graph_lib
 
 CHECKOUT = Path(__file__).resolve().parents[2]
@@ -34,6 +36,7 @@ TINY_CONFIG = {
     # float32 reference than the stated precision is
     "limits": {"max_err_ratio": 0.05, "bf16_exact_share": 0.3},
 }
+GCN = harness.load_model(BENCH, TINY_CONFIG)
 TINY_TRAFFIC = {
     "closed": {"loop": "closed", "entry": "infer", "clients": 2, "pool": 3,
                "keep": 0.9, "warm_requests": 2},
@@ -46,9 +49,12 @@ TINY_TRAFFIC = {
 
 def tiny_bench(tmp_path, loop: str) -> tuple:
     """A bench directory holding only a test configuration, a test
-    traffic file and the real metric readers; returns it and its cell."""
-    for kind in ("configs", "traffic"):
+    traffic file, the real model files and the real metric readers;
+    returns it and its cell."""
+    for kind in ("configs", "traffic", "models"):
         (tmp_path / kind).mkdir()
+    for p in (BENCH / "models").glob("*.py"):
+        os.symlink(p, tmp_path / "models" / p.name)
     (tmp_path / "configs" / "tiny.json").write_text(json.dumps(TINY_CONFIG))
     (tmp_path / "traffic" / f"tiny.{loop}.json").write_text(
         json.dumps(TINY_TRAFFIC[loop]))
@@ -131,6 +137,27 @@ def test_trace_reduction_averages_busy_over_devices():
     assert s.busy_s == pytest.approx((0.030 + 0.1) / 2)
 
 
+@pytest.mark.parametrize("modules, want_ms", [
+    ([("jit__stage_x(4)", 60, 2), ("jit__unstage_y(5)", 70, 1),
+      ("jit__stage_x(4)", 98, 4)], 5 / 4),
+    ([], None),
+], ids=["staged", "no_staging_modules"])
+def test_staging_device_time_per_request(modules, want_ms):
+    """``stage_device_ms_per_req``: both staging modules' device time in
+    the window (the last one clipped at its close) over the answered
+    requests; nothing where the program ran no staging module."""
+    ms = 1_000_000
+    planes = synthetic_planes()
+    planes["/device:TPU:0"]["XLA Modules"] += [
+        (name, s * ms, d * ms) for name, s, d in modules]
+    run = harness.Run(seconds=0.1, setup_s=1.0, records=[],
+                      answered=[1] * 4, latencies_s=[0.1], flops_answered=0,
+                      chips=1, peak_flops=None, counters=None,
+                      queue_waits_s=None, trace=trace.reduce(planes))
+    got = harness.load_reader(BENCH, "stage_device_ms_per_req")(run)
+    assert got == (None if want_ms is None else pytest.approx(want_ms))
+
+
 def test_trace_reduction_needs_the_window_annotation():
     planes = synthetic_planes()
     planes["/host:CPU"]["python"] = planes["/host:CPU"]["python"][1:]
@@ -142,13 +169,15 @@ def test_trace_reduction_needs_the_window_annotation():
 @pytest.mark.parametrize("cfg", [c["name"] for c in BENCHMARK["configs"]])
 def test_flops_per_configuration(cfg):
     config = harness.load_json(BENCH, "configs", cfg)
-    for g in harness.build_graphs(config):
+    model = harness.load_model(BENCH, config)
+    for g in harness.build_graphs(config, model):
         n, nnz = g.csr.shape[0], g.csr.nnz
-        f, h, c = g.widths
+        spec = next(s for s in config["graphs"] if s["name"] == g.name)
+        f, h, c = (spec["n_features"], config["model"]["d_hidden"],
+                   spec["n_classes"])
         want = (2 * n * f * h + 2 * nnz * h) + (2 * n * h * c + 2 * nnz * c)
         assert g.flops == want
-        spec = next(s for s in config["graphs"] if s["name"] == g.name)
-        assert n == spec["n_vertices"] and f == spec["n_features"]
+        assert n == spec["n_vertices"]
         # A_tilde holds the published graph's edges, each direction
         # once, and one self loop per vertex
         assert nnz == spec["n_edges"] + n
@@ -157,8 +186,39 @@ def test_flops_per_configuration(cfg):
 
 def test_pubmed_flops_match_the_hand_count():
     # 2 x 19717 x 500 x 128 dominates: ~2.56 GFLOP a request
-    assert flops.gcn_flops(19717, 88648 + 19717,
-                           [500, 128, 3]) == pytest.approx(2.56e9, rel=0.01)
+    assert GCN.gcn_flops(19717, 88648 + 19717,
+                         [500, 128, 3]) == pytest.approx(2.56e9, rel=0.01)
+
+
+# The GCN's parameters, operation counts and references for one seed,
+# read before they moved into ``models/gcn.py``: the model file has to
+# make, count and compute them as the harness did before it.
+PIN_SEED = 2**31 + 17
+PIN_PARAMS = {
+    "g0": "5d069ca76c424213a4cb185a81577b7d5bb9f76a29f5f224aeb3d2f83d7feb71",
+    "g1": "70eefabee4e4e3d17f4b9a606c3bb86d7be4858704f7526acced7c88349926f0"}
+PIN_FLOPS = {"g0": 339000.0, "g1": 226000.0}
+# sha256 of g0's logits for its first pool entry, each side, to the bit
+PIN_LOGITS = {"reference": "95166d314af1528b",
+              "reference_stated": "d4607334ecc83c26",
+              "reference_bf16": "e88b5423b0f3dae9",
+              "reference_fp8": "fecea2209eb0edc4"}
+
+
+def test_the_gcn_model_file_makes_counts_and_computes_as_before():
+    gs = harness.build_graphs(TINY_CONFIG, GCN)
+    harness.make_params(PIN_SEED, TINY_CONFIG, GCN, gs)
+    harness.make_pools(PIN_SEED, TINY_CONFIG, TINY_TRAFFIC["closed"], gs)
+    for g in gs:
+        digest = hashlib.sha256()
+        for w in g.params:
+            digest.update(np.asarray(w).tobytes())
+        assert digest.hexdigest() == PIN_PARAMS[g.name]
+        assert g.flops == PIN_FLOPS[g.name]
+    g = gs[0]
+    for side, want in PIN_LOGITS.items():
+        y = getattr(reference, side)(GCN.forward, g.csr, g.pool[0], g.params)
+        assert hashlib.sha256(y.tobytes()).hexdigest()[:16] == want, side
 
 
 # --------------------------------------------------------- traffic ----
@@ -215,6 +275,69 @@ def test_a_new_config_and_traffic_need_no_edit_of_the_core(tmp_path):
     assert cell.traffic == TINY_TRAFFIC["closed"]
     assert [m["name"] for m in cell.metrics] == [
         m["name"] for m in BENCHMARK["end_to_end"]]
+
+
+# A model the harness has never seen: one linear layer ``A_tilde X W``
+# with its weight in a dict, which the program serves as a GCN of one
+# layer.
+TOY_MODEL = '''
+import functools
+
+import jax
+import jax.numpy as jnp
+
+
+def make_params(key, model, spec):
+    return {"w": jax.random.uniform(
+        key, (spec["n_features"], spec["n_classes"]), jnp.float32,
+        -model["scale"], model["scale"])}
+
+
+def flops(csr, model, spec):
+    c = spec["n_classes"]
+    return 2.0 * csr.shape[0] * spec["n_features"] * c + 2.0 * csr.nnz * c
+
+
+@functools.partial(jax.jit, static_argnames=("n", "dtype", "precision",
+                                             "operands"))
+def forward(rows, cols, vals, x, params, *, n, dtype, precision,
+            operands=None):
+    def rounded(a):
+        return a if operands is None else a.astype(operands).astype(dtype)
+    hw = jnp.dot(rounded(x.astype(dtype)),
+                 rounded(params["w"].astype(dtype)), precision=precision)
+    h = jax.ops.segment_sum(vals.astype(dtype)[:, None] * hw[cols], rows,
+                            num_segments=n)
+    return h.astype(jnp.float32)
+
+
+def register(engine, name, csr, *, reorder, params):
+    return engine.register(name, csr, reorder=reorder,
+                           weights=[params["w"]])
+'''
+
+
+def test_a_new_model_needs_only_new_files(tmp_path):
+    bench_dir, bench = tiny_bench(tmp_path, "closed")
+    (bench_dir / "models" / "toy.py").write_text(TOY_MODEL)
+    (bench_dir / "configs" / "tiny.json").write_text(json.dumps(
+        dict(TINY_CONFIG, model={"kind": "toy", "scale": 0.5})))
+    harness.TRACE_DIR = tmp_path / "trace"
+    cell = harness.load_cell(bench, "tiny.closed", False, bench_dir)
+    assert cell.model.__file__ == str(bench_dir / "models" / "toy.py")
+    out = harness.run_cell(cell, seed=2**31 + 23, seconds=1.0, trace=False,
+                           t_start=harness.clock(), bench_dir=bench_dir,
+                           log=lambda m: None)
+    assert out["correct"], out
+    assert out["failed"] == 0 and out["attempted"] > 0
+
+
+def test_a_model_kind_without_a_file_fails_before_any_run(tmp_path):
+    bench_dir, bench = tiny_bench(tmp_path, "closed")
+    (bench_dir / "configs" / "tiny.json").write_text(json.dumps(
+        dict(TINY_CONFIG, model={"kind": "nosuch"})))
+    with pytest.raises(FileNotFoundError, match="model kind 'nosuch'"):
+        harness.load_cell(bench, "tiny.closed", False, bench_dir)
 
 
 # ------------------------------------------------------- whole runs ----
@@ -312,9 +435,10 @@ def control_readings(cfg: str, seed: int) -> dict:
     ws = [rng.standard_normal((p, q)).astype(np.float32)
           * np.float32(np.sqrt(2.0 / (p + q)))
           for p, q in zip(dims[:-1], dims[1:])]
-    refs = (reference.reference(a, x, ws),
-            reference.reference_stated(a, x, ws))
-    return {side: harness.numbers(fn(a, x, ws), *refs)
+    fwd = harness.load_model(BENCH, config).forward
+    refs = (reference.reference(fwd, a, x, ws),
+            reference.reference_stated(fwd, a, x, ws))
+    return {side: harness.numbers(fn(fwd, a, x, ws), *refs)
             for side, fn in (("fp8", reference.reference_fp8),
                              ("bf16", reference.reference_bf16))}
 
@@ -337,14 +461,14 @@ def test_the_bf16_control_in_the_programs_place_is_incorrect(monkeypatch):
     built = {}
     real_build = harness.build_graphs
 
-    def build(config):
-        gs = real_build(config)
-        built.update((g.name, g) for g in gs)
+    def build(config, model):
+        gs = real_build(config, model)
+        built.update((g.name, (g, model)) for g in gs)
         return gs
 
     def control(self, name, x):
-        g = built[name]
-        return reference.reference_bf16(g.csr, x, g.weights)
+        g, model = built[name]
+        return reference.reference_bf16(model.forward, g.csr, x, g.params)
     monkeypatch.setattr(harness, "build_graphs", build)
     monkeypatch.setattr(serving_mod.Engine, "infer", control)
     name = next(w["name"] for w in BENCHMARK["workloads"]

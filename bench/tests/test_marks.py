@@ -13,8 +13,8 @@ import jax.numpy as jnp
 import pytest
 
 from bench.lib import harness, marks, trace
-from test_harness import (BENCH, TINY_CONFIG, cpu_peaks,  # noqa: F401
-                          synthetic_planes, tiny_bench)
+from test_harness import (BENCH, GCN, TINY_CONFIG,  # noqa: F401
+                          cpu_peaks, synthetic_planes, tiny_bench)
 
 MS = 1_000_000
 SCOPE_METRICS = ("combine_ms_per_req", "agg_dense_ms_per_req",
@@ -186,14 +186,15 @@ def test_traced_closed_infer_run_reads_the_staging_metrics(tmp_path):
     m = out["metrics"]
     assert m["pad_ms_per_req"]["value"] > 0
     assert m["h2d_ms_per_req"]["value"] > 0
-    # every request puts its features, padded to the class, on the device
+    # every request puts its features [n_cols, f], unpadded and
+    # unpermuted, on the device; the class's padding is made there
     from repro.engine import Engine
-    (g,) = harness.build_graphs(ONE_GRAPH)
+    (g,) = harness.build_graphs(ONE_GRAPH, GCN)
     h = Engine().register(g.name, harness._program_csr(g.csr),
                           reorder=ONE_GRAPH["reorder"])
-    rows = h.sclass.n_col_tiles * h.sclass.tile
+    assert h.sclass.n_col_tiles * h.sclass.tile > g.csr.shape[1]
     assert m["h2d_mb_per_req"]["value"] == pytest.approx(
-        rows * g.widths[0] * 4 / 1e6)
+        g.csr.shape[1] * g.spec["n_features"] * 4 / 1e6)
     # a CPU trace has no device plane to read scopes from, but carries
     # the executor's HLO and its scopes
     for name in SCOPE_METRICS:
