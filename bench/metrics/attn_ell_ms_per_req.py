@@ -1,0 +1,15 @@
+"""Device time of the ops in named scope ``attn.ell`` (the GAT's
+attention weights and weighted sum on the ELL units) inside the modules
+that carry the engine's scopes (the GAT executor's ``jit_fwd``) in the
+traced window, per request answered in it (`bench.lib.marks`)."""
+from bench.lib import marks
+
+SCOPE = "attn.ell"
+
+
+def read(run):
+    m = marks.of_run(run)
+    if m is None or not run.answered:
+        return None
+    s = m.scope_s(SCOPE)
+    return None if s is None else s / len(run.answered) * 1e3

@@ -34,6 +34,7 @@ import threading
 import jax
 import jax.numpy as jnp
 
+from repro.core.gat import gat_forward
 from repro.core.hybrid_spmm import gcn_forward, hybrid_spmm
 from repro.obs.metrics import Counter, MetricsRegistry
 from repro.obs.trace import NULL_TRACER
@@ -400,6 +401,33 @@ class ExecutorCache:
             key = self._gcn_key(sc, f_in, w_shapes) + ("batch", batch)
             return self._get(
                 key, lambda: jax.jit(jax.vmap(self._gcn_build(sc))))
+
+    # ------------------------------------------------------------- gat -----
+    def _gat_build(self, sc):
+        meta = sc.to_meta()
+
+        def fwd(part, x, params):
+            return gat_forward(part, x, params, meta=meta)
+        return fwd
+
+    def gat(self, sc: ShapeClass, f_in: int, shapes: tuple):
+        """Executor for the GAT forward (`repro.core.gat`, XLA) over one
+        padded graph; ``shapes`` as `gat_param_shapes` gives them.
+
+        Signature: fn(part, x[n_cols_padded, f_in], params) ->
+        logits[n_rows_padded, classes].
+        """
+        with self._lock:
+            return self._get(("gat", sc, f_in, shapes),
+                             lambda: jax.jit(self._gat_build(sc)))
+
+    def gat_batched(self, sc: ShapeClass, f_in: int, shapes: tuple,
+                    batch: int):
+        """vmapped GAT executor over a stacked class group of ``batch``
+        graphs."""
+        with self._lock:
+            return self._get(("gat", sc, f_in, shapes, "batch", batch),
+                             lambda: jax.jit(jax.vmap(self._gat_build(sc))))
 
     def summary(self) -> str:
         with self._lock:

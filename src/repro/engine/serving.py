@@ -34,7 +34,7 @@ import collections
 import dataclasses
 import threading
 import time
-from typing import NamedTuple, Optional
+from typing import Callable, NamedTuple, Optional
 
 import numpy as np
 import jax
@@ -42,6 +42,7 @@ import jax.numpy as jnp
 import jax.tree_util as jtu
 
 from repro.core.formats import CSRMatrix, PartitionMeta, TriPartition
+from repro.core.gat import gat_param_shapes
 from repro.core.partition import PartitionConfig, analyze_and_partition
 from repro.core.reorder import reorder as reorder_csr
 from repro.obs.metrics import Counter, MetricsRegistry
@@ -52,6 +53,49 @@ from .executor import ExecutorCache
 from .lifecycle import RetirementPlan
 from .shape_class import (ClassNeed, ClassRegistry, ShapeClass, ShapePolicy,
                           class_requirements, pad_to_class, unpad_from_class)
+
+
+@dataclasses.dataclass(frozen=True)
+class ModelKind:
+    """What the engine knows of one served model: a further model is one
+    more entry of `MODELS`."""
+
+    # its `ExecutorCache` methods, `<executor>` and `<executor>_batched`
+    executor: str
+    params_arg: str     # `Engine.register`'s keyword for its parameters
+    backends: tuple     # executor backends it runs on
+    square: bool        # attends over A's rows, so A must be square
+    # parameter pytree -> the shapes the group and executor keys carry
+    shapes: Callable[[object], tuple]
+    # shapes -> (features in, logits out)
+    io_widths: Callable[[tuple], tuple]
+    # (f_in, shapes) -> (aggregation widths, [f_in, f_out] matmuls) a
+    # request, for the cost prior `Engine.latency_prior`
+    cost_widths: Callable[[int, tuple], tuple]
+    # shapes -> attention heads over all layers, 0 for none
+    heads: Callable[[tuple], int]
+
+
+# The models `Engine.register` serves: the paper's GCN
+# (`core.hybrid_spmm.gcn_forward`, weights [f_in, f_out] a layer) and
+# GAT (`core.gat.gat_forward`; a GAT's last layer averages its heads, so
+# it gives F' logits).
+MODELS = {
+    "gcn": ModelKind(
+        executor="gcn", params_arg="weights", backends=("xla", "pallas"),
+        square=False,
+        shapes=lambda ws: tuple(tuple(w.shape) for w in ws),
+        io_widths=lambda s: (s[0][0], s[-1][-1]),
+        cost_widths=lambda f_in, s: ([f_in] + [w[1] for w in s], s),
+        heads=lambda s: 0),
+    "gat": ModelKind(
+        executor="gat", params_arg="params", backends=("xla",), square=True,
+        shapes=gat_param_shapes,
+        io_widths=lambda s: (s[0][0][0], s[-1][1][1]),
+        cost_widths=lambda f_in, s: ([h * (f + 1) for _, (h, f) in s],
+                                     tuple(w for w, _ in s)),
+        heads=lambda s: sum(h for _, (h, _f) in s)),
+}
 
 
 @dataclasses.dataclass
@@ -67,7 +111,11 @@ class GraphHandle:
     # ``part``, or None for a graph registered in input order
     perm: Optional[jax.Array]
     inv_perm: Optional[jax.Array]
-    weights: Optional[list]     # per-graph GCN weights (jnp), or None
+    # the served model (one of MODELS) and its parameter pytree on the
+    # device (GCN: [f_in, f_out] weights a layer), or None
+    model: str
+    params: object
+    param_shapes: tuple = ()    # its `ModelKind.shapes`
     preprocess_s: float = 0.0
     # exact pre-snapping shape requirements, kept so the lifecycle can
     # re-classify this graph on retirement without re-partitioning
@@ -80,11 +128,17 @@ class GraphHandle:
     def n_rows(self) -> int:
         return self.meta.n_rows
 
+    @property
+    def weights(self) -> Optional[list]:
+        """A GCN handle's weight list (its ``params``); None for another
+        model."""
+        return self.params if self.model == "gcn" else None
+
 
 class _Placed(NamedTuple):
     """A graph's device arrays on one device."""
     part: object
-    weights: Optional[list]
+    params: object
     perm: Optional[jax.Array]
     inv_perm: Optional[jax.Array]
 
@@ -177,6 +231,12 @@ class Engine:
         # Requests whose row permutation `_pad_x` ran on the device.
         self._device_permutes = Counter("engine.device_permutes",
                                         self.metrics)
+        # GAT requests' attention work, over layers x heads: the edge
+        # slots their classes score (padding included) and the real
+        # edges among them (`_count_attention`).
+        self._attn_edge_slots = Counter("engine.attn_edge_slots",
+                                        self.metrics)
+        self._attn_edges = Counter("engine.attn_edges", self.metrics)
         # Request tracer (repro.obs.trace): off by default; a serving
         # frontend constructed with `tracer=` calls `attach_tracer`,
         # which also fans the tracer out to the executor cache and the
@@ -219,15 +279,36 @@ class Engine:
     # --------------------------------------------------------- offline -----
     def register(self, name: str, csr: CSRMatrix, *,
                  reorder: Optional[str] = None, labels=None,
-                 weights=None,
+                 weights=None, model: str = "gcn", params=None,
                  part_meta: Optional[tuple] = None) -> GraphHandle:
         """Preprocess one graph into its shape class.
 
         ``reorder`` names a `repro.core.reorder` strategy (None skips).
-        ``weights`` (list of [f_in, f_out] arrays) enables ``infer`` /
-        ``serve_batch``. ``part_meta=(part, meta)`` skips partitioning
-        for callers that already ran Algorithm 2 themselves.
+        ``model`` names the model ``infer`` / ``serve_batch`` run, one
+        of `MODELS`, and ``params`` its parameters: for ``"gcn"`` a
+        list of [f_in, f_out] weights (``weights`` is the same), for
+        ``"gat"`` one ``{"w", "a_l", "a_r"}`` dict a layer
+        (`repro.core.gat`; xla backend only, over a square graph).
+        Without them the graph serves ``spmm`` only.
+        ``part_meta=(part, meta)`` skips partitioning for callers that
+        already ran Algorithm 2 themselves.
         """
+        kind = MODELS.get(model)
+        if kind is None:
+            raise ValueError(f"unknown model {model!r}; serves {list(MODELS)}")
+        if weights is not None:
+            if model != "gcn" or params is not None:
+                raise ValueError("weights= gives a GCN's parameters; pass "
+                                 "params= alone")
+            params = weights
+        shapes = () if params is None else kind.shapes(params)
+        if self.executors.backend not in kind.backends:
+            raise NotImplementedError(
+                f"{model} runs on the {'/'.join(kind.backends)} backend; "
+                f"this engine's backend is {self.executors.backend!r}")
+        if kind.square and csr.shape[0] != csr.shape[1]:
+            raise ValueError(f"{model} attends over a square graph; "
+                             f"{name!r} is {csr.shape}")
         t0 = time.perf_counter()
         perm = inv_perm = None
         if part_meta is not None:
@@ -248,15 +329,15 @@ class Engine:
         padded, perm, inv_perm = jax.device_put((padded, perm, inv_perm))
         handle = GraphHandle(
             name=name, part=padded, meta=meta, padded_meta=pmeta, sclass=sc,
-            perm=perm, inv_perm=inv_perm,
-            weights=None if weights is None else [jnp.asarray(w)
-                                                  for w in weights],
+            perm=perm, inv_perm=inv_perm, model=model,
+            params=None if params is None else jtu.tree_map(jnp.asarray,
+                                                            list(params)),
+            param_shapes=shapes,
             preprocess_s=time.perf_counter() - t0, need=need)
-        if handle.weights is not None:
+        if params is not None:
             # compile this graph's staging at its `infer` widths now, so
             # its first request does not
-            f_in, classes = (handle.weights[0].shape[0],
-                             handle.weights[-1].shape[-1])
+            f_in, classes = kind.io_widths(shapes)
             self._stage(self.executors, handle,
                         jnp.zeros((meta.n_cols, f_in), jnp.float32), perm)
             self._unstage(self.executors, handle,
@@ -305,12 +386,12 @@ class Engine:
         ``register`` put them. A device's copy is made once, on its
         first dispatch."""
         if device is None:
-            return _Placed(h.part, h.weights, h.perm, h.inv_perm)
+            return _Placed(h.part, h.params, h.perm, h.inv_perm)
         with self._stack_lock:
             placed = h.copies.get(device)
             if placed is None:
                 placed = h.copies[device] = jax.device_put(
-                    _Placed(h.part, h.weights, h.perm, h.inv_perm), device)
+                    _Placed(h.part, h.params, h.perm, h.inv_perm), device)
         return placed
 
     # ---------------------------------------------------------- online -----
@@ -394,30 +475,55 @@ class Engine:
         return cfg
 
     def infer(self, name: str, x) -> jnp.ndarray:
-        """GCN forward logits for one request."""
+        """Logits of the graph's registered model (its ``model``: the
+        GCN or GAT) for one request's features ``x`` [n_cols, f_in]."""
         h = self._graphs[name]
-        if h.weights is None:
-            raise ValueError(f"graph {name!r} registered without weights")
-        w_shapes = tuple(tuple(w.shape) for w in h.weights)
+        key = self._group_key(h, x)
         xp = self._pad_x(h, x)
         with self.tracer.span("launch", "engine"):
-            fn = self.executors.gcn(h.sclass, int(x.shape[1]), w_shapes)
-            y = fn(h.part, xp, h.weights)
+            fn = self._forward(self.executors, key)
+            y = fn(h.part, xp, h.params)
+        self._count_attention(h)
         return self._unpad_y(h, y)
 
     def _group_key(self, h: GraphHandle, x) -> tuple:
-        if h.weights is None:
-            raise ValueError(f"graph {h.name!r} registered without weights")
-        w_shapes = tuple(tuple(w.shape) for w in h.weights)
-        return (h.sclass, int(x.shape[1]), w_shapes)
+        if h.params is None:
+            raise ValueError(
+                f"graph {h.name!r} ({h.model}) was registered without "
+                f"its parameters: register it with "
+                f"{MODELS[h.model].params_arg}= to infer")
+        return (h.sclass, int(x.shape[1]), h.param_shapes, h.model)
 
     def group_key(self, name: str, x) -> tuple:
-        """The (shape class, f_in, weight shapes) tuple that decides
-        which requests may share one ``serve_group`` dispatch. The
-        serving frontend groups on exactly this — single source of
+        """The (shape class, f_in, parameter shapes, model) tuple that
+        decides which requests may share one ``serve_group`` dispatch.
+        The serving frontend groups on exactly this — single source of
         truth, so frontend grouping can never drift from what
         ``serve_group`` accepts."""
         return self._group_key(self._graphs[name], x)
+
+    @staticmethod
+    def _forward(ex: ExecutorCache, key: tuple, batch: int = 0):
+        """``ex``'s forward executor of group ``key``: one graph, or
+        ``batch`` stacked graphs (vmapped) when ``batch`` > 0."""
+        sc, f_in, shapes, model = key
+        name = MODELS[model].executor
+        if batch:
+            return getattr(ex, name + "_batched")(sc, f_in, shapes, batch)
+        return getattr(ex, name)(sc, f_in, shapes)
+
+    def _count_attention(self, h: GraphHandle) -> None:
+        """Add one request of ``h``, if a GAT, to the attention
+        counters: the edge slots its class scores (padding included) and
+        the graph's real edges, each over layers x heads."""
+        heads = MODELS[h.model].heads(h.param_shapes)
+        if not heads:
+            return
+        sc = h.sclass
+        slots = (sc.n_dense_tiles * sc.tile * sc.tile
+                 + sc.ell_units * sc.r_block * sc.ell_kmax + sc.coo_nnz)
+        self._attn_edge_slots.inc(heads * slots)
+        self._attn_edges.inc(heads * h.meta.nnz)
 
     def serve_batch(self, requests) -> list:
         """Serve [(name, x), ...]; returns logits in request order.
@@ -508,11 +614,10 @@ class Engine:
             elif key != key0:
                 raise ValueError(
                     f"serve_group members must share one (class, f_in, "
-                    f"weight-shapes) key; {requests[0][0]!r} and {name!r} "
-                    f"differ")
+                    f"parameter shapes, model) key; {requests[0][0]!r} "
+                    f"and {name!r} differ")
             xp = prepared[i] if prepared is not None else None
             members.append((i, h, x, xp))
-        sc, f_in, w_shapes = key0
         # Deliberate unguarded builds-counter read: a stale value only
         # over-reports cold, which skips a warm sample and never poisons
         # the latency EWMA — see _completion_meta.
@@ -526,11 +631,12 @@ class Engine:
             i, h, x, xp = members[0]
             args = {"n": 1} if tr.enabled else None
             with tr.span("pad", "engine", args):
-                fn = ex.gcn(sc, f_in, w_shapes)
+                fn = self._forward(ex, key0)
                 xpad = pad(h, x, xp)
                 placed = self._placed(h, device)
-            outs = [self._unpad_y(h, fn(placed.part, xpad, placed.weights),
+            outs = [self._unpad_y(h, fn(placed.part, xpad, placed.params),
                                   device, ex)]
+            self._count_attention(h)
             meta = self._completion_meta(outs, builds0, ex)
             if inj.enabled:
                 outs, meta = self._inject_async(inj, requests, outs, meta)
@@ -545,7 +651,7 @@ class Engine:
         padded = members + [members[-1]] * (bs - len(members))
         args = {"n": len(members), "batch": bs} if tr.enabled else None
         with tr.span("pad", "engine", args):
-            fn = ex.gcn_batched(sc, f_in, w_shapes, bs)
+            fn = self._forward(ex, key0, bs)
             # one stack per device: a lane's stack lives where it runs
             stack_key = (device,) + tuple(h.name for _, h, _, _ in padded)
             placed = [self._placed(h, device) for _, h, _, _ in padded]
@@ -556,25 +662,27 @@ class Engine:
                     part_stack = jtu.tree_map(
                         lambda *leaves: jnp.stack(leaves),
                         *[p.part for p in placed])
-                    w_stack = jtu.tree_map(
-                        lambda *ws: jnp.stack(ws),
-                        *[p.weights for p in placed])
+                    params_stack = jtu.tree_map(
+                        lambda *leaves: jnp.stack(leaves),
+                        *[p.params for p in placed])
                     while len(self._stacks) >= self._max_stacks:
                         self._stacks.popitem(last=False)       # LRU out
                         self._stack_evictions.inc()
-                    stacks = self._stacks[stack_key] = (part_stack, w_stack)
+                    stacks = (part_stack, params_stack)
+                    self._stacks[stack_key] = stacks
                 else:
                     self._stacks.move_to_end(stack_key)        # mark MRU
                     self._stack_hits.inc()
-            part_stack, w_stack = stacks
+            part_stack, params_stack = stacks
             # each member is staged once; the batch's filler slots
             # repeat the last member's staged features
             xs = [pad(h, x, xp) for _, h, x, xp in members]
             x_stack = jnp.stack(xs + [xs[-1]] * (bs - len(members)))
-        ys = fn(part_stack, x_stack, w_stack)
+        ys = fn(part_stack, x_stack, params_stack)
         results: list = [None] * len(members)
         for j, (i, h, _, _) in enumerate(members):
             results[i] = self._unpad_y(h, ys[j], device, ex)
+            self._count_attention(h)
         meta = self._completion_meta(results, builds0, ex)
         if inj.enabled:
             results, meta = self._inject_async(inj, requests, results, meta)
@@ -645,12 +753,14 @@ class Engine:
         if peaks is None or not hasattr(sc, "ell_mac_capacity"):
             return None
         f_in = key[1]
-        w_shapes = key[2] if len(key) > 2 else ()
+        kind = MODELS[key[3] if len(key) > 3 else "gcn"]
+        widths, w_shapes = kind.cost_widths(
+            f_in, key[2] if len(key) > 2 else ())
         macs = (sc.ell_mac_capacity
                 + sc.n_dense_tiles * sc.tile * sc.tile + sc.coo_nnz)
         n_rows = sc.n_row_tiles * sc.tile
-        widths = [f_in] + [w[1] for w in w_shapes]
-        # per layer: one hybrid SpMM at that width + the dense weight GEMM
+        # per layer: one aggregation over the class's slots at each
+        # width + the dense weight GEMM
         flops = 2.0 * macs * sum(widths)
         flops += sum(2.0 * n_rows * a * b for a, b in w_shapes)
         byts = 4.0 * (macs + n_rows * sum(widths))
@@ -855,6 +965,8 @@ class Engine:
             "registry": self.registry.stats(),
             "h2d_bytes": self._h2d_bytes.value,
             "device_permutes": self._device_permutes.value,
+            "attn_edge_slots": self._attn_edge_slots.value,
+            "attn_edges": self._attn_edges.value,
             **stack,
         }
         if self._tuner is not None:

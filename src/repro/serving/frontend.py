@@ -260,10 +260,10 @@ class RequestQueue:
         Grouping
             The request joins the pending queue for
             ``engine.group_key(name, x)`` — (shape class, feature
-            width, weight shapes). Only same-key requests ever share a
-            dispatch; if the graph's class is retired by the lifecycle
-            mid-flight, `drain_class` flushes the old key first, so the
-            future still resolves.
+            width, parameter shapes, model). Only same-key requests
+            ever share a dispatch; if the graph's class is retired by
+            the lifecycle mid-flight, `drain_class` flushes the old key
+            first, so the future still resolves.
 
         Thread-safe. Callers block only for the admission checks —
         except while a lifecycle retirement barrier (`drain_class`)

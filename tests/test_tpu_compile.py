@@ -172,3 +172,37 @@ def test_request_staging_compiles(graph, permuted, served, one_chip):
                              jnp.float32, sharding=one_chip)
     unstaged = _unstage_fn(h.n_rows).lower(y, perm).compile().as_text()
     assert f"f32[{h.n_rows},{N_CLASSES}]" in unstaged.split("ENTRY", 1)[1]
+
+
+@pytest.mark.parametrize("cfg", ["gcn-paper.pubmed", "gat.pubmed"])
+def test_the_bf16_control_rounds_its_answer_to_bfloat16(cfg, one_chip):
+    """The benchmark's all-bfloat16 control (``bench/lib/reference.py``
+    over the configuration's model file), compiled for the chip, casts a
+    bfloat16 array to its float32 answer. XLA on the TPU may fuse a last
+    elementwise op or reduction into that cast and keep float32 (a
+    GAT's head average did), and the control then no longer fails
+    ``bf16_exact_share``."""
+    import re
+    import sys
+    from pathlib import Path
+    checkout = Path(__file__).resolve().parents[1]
+    if str(checkout) not in sys.path:
+        sys.path.insert(0, str(checkout))
+    from bench.lib import harness
+    config = harness.load_json(checkout / "bench", "configs", cfg)
+    model = harness.load_model(checkout / "bench", config)
+    n, nnz, f, classes = 64, 256, 16, 3
+    params = model.make_params(jax.random.key(0), config["model"],
+                               {"n_features": f, "n_classes": classes})
+    on = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    text = model.forward.lower(
+        on((nnz,), jnp.int32), on((nnz,), jnp.int32),
+        on((nnz,), jnp.float32), on((n, f), jnp.float32),
+        jax.tree.map(lambda a: _on(one_chip, a), params), n=n,
+        dtype=jnp.bfloat16, precision=jax.lax.Precision.DEFAULT,
+    ).compile().as_text().split("ENTRY", 1)[1]
+    root = re.search(r"ROOT %\S+ = f32\[(\d+),(\d+)\]\S* convert\(%([^)]+)\)",
+                     text)
+    assert root is not None and root.groups()[:2] == (str(n), str(classes))
+    assert re.search(rf"%{re.escape(root.group(3))} = bf16\[{n},{classes}\]",
+                     text)
